@@ -1,8 +1,9 @@
 """Mesh-kernel benchmarks: Pallas (interpret on CPU) vs pure-jnp reference.
 
-On this CPU container the timing is indicative only (interpret mode runs the
-kernel body op-by-op); the derived column also reports the kernel's analytic
-VMEM working set and FLOPs — the numbers that matter for the TPU target.
+On the CPU the timing is indicative only (interpret mode runs the kernel
+body op-by-op) and is never a device number; the derived column also
+reports the kernel's analytic VMEM working set and FLOPs — the numbers
+that matter for the TPU target.
 """
 
 from __future__ import annotations

@@ -11,6 +11,9 @@ previous run the CI smoke job diffs fresh numbers against
     BENCH_SMOKE=1 PYTHONPATH=src python -m benchmarks.run \
         --only kernel --json BENCH_new.json            # CI tiny config
     PYTHONPATH=src python -m benchmarks.run --suite kernels --json -  # no file
+
+The platform comes from the environment (``JAX_PLATFORMS``): on a TPU
+the kernels compile, on the CPU they run in the Pallas interpreter.
 """
 
 from __future__ import annotations
@@ -31,8 +34,6 @@ if os.environ.get("BENCH_HOST_DEVICES"):
         f"device_count={int(os.environ['BENCH_HOST_DEVICES'])}").strip()
 
 import jax
-
-jax.config.update("jax_platform_name", "cpu")
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_JSON = os.path.join(_REPO_ROOT, "BENCH_kernels.json")
@@ -90,6 +91,9 @@ def main(argv=None) -> int:
                          "BENCH_kernels.json at the repo root when the "
                          "kernels suite runs; '-' disables the file")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.json is None and args.suite in ("all", "kernels"):
         args.json = DEFAULT_JSON
     if args.json == "-":

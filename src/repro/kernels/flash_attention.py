@@ -29,6 +29,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -84,7 +86,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
     bq*bk*4 — well inside VMEM for hd <= 256.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     b, h, sq, hd = q.shape
     skv = k.shape[2]
     bq_ = min(bq, sq)

@@ -47,16 +47,19 @@ Kernels:
     batch-grid steps.  See DESIGN.md ("Backward pass").
 
 Validated against ``ref.py`` and the hardware-model reference in interpret
-mode (this container is CPU-only; TPU is the compilation target).
+mode on the CPU (the test suite), compiled for a described TPU v5e in
+``tests/test_tpu_compile.py``, and run on the chip by ``chip_smoke.py``.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _cmul(ar, ai, br, bi):
@@ -524,23 +527,30 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # next layer as a real signal (zero imaginary planes) — inter-layer
 # activations never touch HBM.  Gains are [L, To, Ti, 12, P]: rows 0-3
 # g0, 4-7 g1, 8-11 g2, each as (even re, even im, odd re, odd im).
-# Coefficients/parities stack to [L, To, Ti, C, 8, P] / [L, To, Ti, C, 1]
-# with identity-column padding to the network-wide C (see
-# ``repro.kernels.schedule.DeepGridSchedule``).  The pallas grid is the
-# batch alone — inter-layer re-detection needs every row of a layer, so
-# one grid step carries one batch block through the entire network.
+# Coefficients stack to [L, To, Ti, C, 8, P] over the aligned column
+# schedule of ``repro.kernels.schedule.DeepGridSchedule``: every column
+# has ONE parity across a layer's tiles (an SMEM ``[L, C]`` table per
+# mesh), tiles whose own schedules disagree sit the column out as an
+# identity cell.  The pallas grid is the batch alone — inter-layer
+# re-detection needs every row of a layer, so one grid step carries one
+# batch block through the entire network.
+#
+# Layout: planes are [To, Ti, B, P] — tile axes leading, the batch block
+# on sublanes, the P = n/2 pair slots on lanes.  Every tile-structure
+# step is then a leading-axis operation the chip does per vreg with no
+# relayout: broadcasting an input over the To rows, the row combine (a
+# sum over Ti), its transpose (a sum over To), and handing a layer's To
+# rows to the next layer's Ti input tiles.  Coefficient and gain rows
+# load as [To, Ti, 1, P] slices and broadcast over the batch sublanes;
+# coefficient gradients reduce over the sublanes back to that shape.
 #
 # The grid's To*Ti tiles are NOT unrolled: a layer's tiles are
-# independent sweeps over the same (padded) column count, so the body
-# stacks them into [B, To, Ti, P] planes and runs ONE column sweep per
-# mesh stage with [To, Ti, 8, P] coefficient slabs broadcast over the
-# batch.  The per-column even/odd pairing becomes a branch-free
-# parity-masked select (the rotation math runs once per lane; parity
-# only reroutes operands and results), so tiles with *different* column
-# parities — mixed Reck/Clements grids — coexist in one stacked sweep.
-# Only the L layer steps unroll (each depends on the previous layer's
-# detected rows), keeping the emitted program O(L * C) vector ops
-# instead of O(L * To * Ti * C) scalar-tile ops.
+# independent sweeps over the same aligned column count, so the body
+# runs ONE column sweep per mesh stage over the stacked planes, each
+# column taking its even/odd body from the parity table.  Only the L
+# layer steps unroll (each depends on the previous layer's detected
+# rows), keeping the emitted program O(L) column loops instead of
+# O(L * To * Ti).
 #
 # In-kernel re-detection between layers is *exact*, not an approximation:
 # the combined row output z is held coherently in VMEM, so |z| computed
@@ -555,9 +565,8 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # Residuals follow the single-layer kernel's rule: everything inside a
 # mesh is recomputed by the reversed inverse/adjoint sweep (no per-column
 # state), but |z| is not invertible, so each tile saves its two pre-gain
-# stage boundaries (post-V, post-U) — 8 stacked [L, B, To, Ti, P] planes
-# total (batch-block axis second, so the stacked sweep saves whole
-# slabs), identical to what the per-layer / per-tile composition would
+# stage boundaries (post-V, post-U) — 8 stacked [L, To, Ti, B, P] planes
+# total, identical to what the per-layer / per-tile composition would
 # have stored, minus all the inter-layer HBM round trips and per-layer
 # kernel launches.  The layer-boundary activations themselves are NOT
 # stored: a layer's input is re-detected from the *previous* layer's
@@ -565,6 +574,38 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # sweep), and the backward unwinds layers in reverse, converting the
 # row-combine's transpose (every tile of a row sees the row's cotangent;
 # each input tile sums its cotangent over rows) entirely in VMEM.
+#
+# VMEM: coefficient, gain and gradient-accumulator tensors are resident
+# for the whole call (one buffer each); batch-blocked planes are double
+# buffered.  On the chip every buffer's last two axes are tiled (8, 128),
+# so a P-lane plane or an (8, P) coefficient slab occupies 128 lanes;
+# :func:`vmem_bytes` counts that, and a call whose count exceeds the
+# default scoped limit raises ``vmem_limit_bytes`` to fit.
+
+#: Mosaic's default scoped-VMEM limit on v5e.
+_SCOPED_VMEM_DEFAULT = 16 * 2**20
+
+#: Room left above the counted buffers for Mosaic's internal scratch
+#: (the sweep state that does not fit the vector registers).
+_VMEM_HEADROOM = 8 * 2**20
+
+
+def vmem_bytes(shape) -> int:
+    """Bytes a 4-byte-element buffer of ``shape`` occupies in TPU VMEM,
+    where the last two axes are tiled (8 sublanes, 128 lanes)."""
+    *lead, sub, lane = (1,) + tuple(shape)
+    return math.prod(lead) * (-(-sub // 8) * 8) * (-(-lane // 128) * 128) * 4
+
+
+def _deep_compiler_params(resident, blocked):
+    """``vmem_limit_bytes`` for a deep-grid call whose resident buffers
+    (one copy) and batch-blocked buffers (double-buffered) need more than
+    the default scoped VMEM; ``None`` when the default suffices."""
+    need = (sum(vmem_bytes(s) for s in resident)
+            + 2 * sum(vmem_bytes(s) for s in blocked) + _VMEM_HEADROOM)
+    if need <= _SCOPED_VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
 def _vshift_down(x):
@@ -586,89 +627,53 @@ def _vlast(x, last):
 def _vcolumn_even(cc, state):
     """Even column over stacked tile planes: rotate (e_p, o_p) in place."""
     er, ei, orr, oi = state
-    c = [cc[..., k, :] for k in range(8)]
-    return _rotate(c, er, ei, orr, oi)
+    return _rotate(cc, er, ei, orr, oi)
 
 
 def _vcolumn_odd(cc, state):
     """Odd column: rotate (o_p, e_{p+1}); the two wrap lanes pass
     through (odd columns hold no cell in the wrap-around pair)."""
     er, ei, orr, oi = state
-    c = [cc[..., k, :] for k in range(8)]
-    a2r, a2i, b2r, b2i = _rotate(c, orr, oi,
+    a2r, a2i, b2r, b2i = _rotate(cc, orr, oi,
                                  _vshift_down(er), _vshift_down(ei))
     return (_vshift_up(b2r, er), _vshift_up(b2i, ei),
             _vlast(a2r, orr), _vlast(a2i, oi))
 
 
-def _vcolumn_mixed(cc, odd, state):
-    """Parity-masked column for grids whose tiles disagree on this
-    column's pairing (e.g. Reck next to Clements).  Branch-free: the
-    rotation math runs exactly once per lane; the [To, Ti, 1] ``odd``
-    mask only reroutes operands and results, so both pairings coexist
-    in one stacked sweep."""
-    er, ei, orr, oi = state
-    c = [cc[..., k, :] for k in range(8)]
-    ar = jnp.where(odd, orr, er)
-    ai = jnp.where(odd, oi, ei)
-    br = jnp.where(odd, _vshift_down(er), orr)
-    bi = jnp.where(odd, _vshift_down(ei), oi)
-    a2r, a2i, b2r, b2i = _rotate(c, ar, ai, br, bi)
-    ner = jnp.where(odd, _vshift_up(b2r, er), a2r)
-    nei = jnp.where(odd, _vshift_up(b2i, ei), a2i)
-    nor = jnp.where(odd, _vlast(a2r, orr), b2r)
-    noi = jnp.where(odd, _vlast(a2i, oi), b2i)
-    return ner, nei, nor, noi
+def _coef_rows(coef_ref, l, c):
+    """Column ``c`` of layer ``l``: its 8 coefficient rows, [To, Ti, 1, P]
+    each (broadcast over the batch sublanes)."""
+    return [coef_ref[l, :, :, c, k:k + 1, :] for k in range(8)]
 
 
-def _parity_code(par_c):
-    """0 = all tiles even, 1 = all odd, 2 = mixed, for one [To, Ti, 1]
-    parity column."""
-    odd = par_c != 0
-    return jnp.where(jnp.all(odd), jnp.int32(1),
-                     jnp.any(odd).astype(jnp.int32) * 2)
-
-
-def _vcolumn(cc, par_c, state):
-    """One mesh column over stacked tile planes [B, To, Ti, P]: ``cc``
-    the column's [To, Ti, 8, P] coefficient slab (broadcast over the
-    batch), ``par_c`` its [To, Ti, 1] parity column.  Uniform columns —
-    the only kind single-plan grids ever see — dispatch to the mask-free
-    even/odd bodies; the masked select only runs when tiles disagree."""
-    return jax.lax.switch(
-        _parity_code(par_c),
-        [lambda s: _vcolumn_even(cc, s),
-         lambda s: _vcolumn_odd(cc, s),
-         lambda s: _vcolumn_mixed(cc, par_c != 0, s)],
-        state)
-
-
-def _vrun_columns(coef, parity, state):
-    """Stacked-tile column sweep: ``coef`` [To, Ti, C, 8, P], ``parity``
-    [To, Ti, C, 1], state planes [B, To, Ti, P] (batch-materialized —
-    fori_loop carries must be full-shape)."""
-    coef = jnp.moveaxis(coef, 2, 0)       # [C, To, Ti, 8, P]
-    parity = jnp.moveaxis(parity, 2, 0)   # [C, To, Ti, 1]
-
+def _vrun_columns(coef_ref, par_ref, l, state):
+    """Stacked-tile column sweep of layer ``l``'s mesh: ``coef_ref``
+    [L, To, Ti, C, 8, P], ``par_ref`` its SMEM [L, C] parity table, state
+    planes [To, Ti, B, P] (batch-materialized — fori_loop carries must be
+    full-shape)."""
     def body(c, s):
-        return _vcolumn(coef[c], parity[c], s)
+        cc = _coef_rows(coef_ref, l, c)
+        return jax.lax.cond(par_ref[l, c] != 0,
+                            functools.partial(_vcolumn_odd, cc),
+                            functools.partial(_vcolumn_even, cc), s)
 
-    return jax.lax.fori_loop(0, coef.shape[0], body, state)
+    return jax.lax.fori_loop(0, coef_ref.shape[3], body, state)
 
 
 def _vconj_dot(xr, xi, gr, gi):
-    """Batch-summed conj(x) * g over stacked planes -> [To, Ti, P] pair."""
-    return (jnp.sum(xr * gr + xi * gi, axis=0),
-            jnp.sum(xr * gi - xi * gr, axis=0))
+    """Batch-summed conj(x) * g over stacked planes -> [To, Ti, 1, P]
+    pair."""
+    return (jnp.sum(xr * gr + xi * gi, axis=2, keepdims=True),
+            jnp.sum(xr * gi - xi * gr, axis=2, keepdims=True))
 
 
 def _vrows_from_pairs(a, ga, b, gb):
-    """The 8 per-coefficient conj-dot gradient rows, stacked [..., 8, P]."""
-    r0, r1 = _vconj_dot(a[0], a[1], ga[0], ga[1])
-    r2, r3 = _vconj_dot(b[0], b[1], ga[0], ga[1])
-    r4, r5 = _vconj_dot(a[0], a[1], gb[0], gb[1])
-    r6, r7 = _vconj_dot(b[0], b[1], gb[0], gb[1])
-    return jnp.stack([r0, r1, r2, r3, r4, r5, r6, r7], axis=-2)
+    """The 8 per-coefficient conj-dot gradient rows, [To, Ti, 1, P]
+    each."""
+    return (_vconj_dot(a[0], a[1], ga[0], ga[1])
+            + _vconj_dot(b[0], b[1], ga[0], ga[1])
+            + _vconj_dot(a[0], a[1], gb[0], gb[1])
+            + _vconj_dot(b[0], b[1], gb[0], gb[1]))
 
 
 def _vcoef_grad_even(s_in, g_out):
@@ -686,89 +691,58 @@ def _vcoef_grad_odd(s_in, g_out):
         (orr, oi), (gor, goi),
         (_vshift_down(er), _vshift_down(ei)),
         (_vshift_down(ger), _vshift_down(gei)))
-    p = rows.shape[-1]
-    return jnp.where(jnp.arange(p) == p - 1, 0.0, rows)
+    lane = jax.lax.broadcasted_iota(jnp.int32, rows[0].shape, 3)
+    wrap = lane == rows[0].shape[3] - 1
+    return tuple(jnp.where(wrap, 0.0, r) for r in rows)
 
 
-def _vcoef_grad_mixed(odd, s_in, g_out):
-    """Masked-pairing coefficient gradient for mixed-parity columns (the
-    same operand rerouting as :func:`_vcolumn_mixed`; odd tiles hold no
-    cell in the wrap lane, so it is zeroed)."""
-    er, ei, orr, oi = s_in
-    ger, gei, gor, goi = g_out
-    ar = jnp.where(odd, orr, er)
-    ai = jnp.where(odd, oi, ei)
-    br = jnp.where(odd, _vshift_down(er), orr)
-    bi = jnp.where(odd, _vshift_down(ei), oi)
-    gar = jnp.where(odd, gor, ger)
-    gai = jnp.where(odd, goi, gei)
-    gbr = jnp.where(odd, _vshift_down(ger), gor)
-    gbi = jnp.where(odd, _vshift_down(gei), goi)
-    rows = _vrows_from_pairs((ar, ai), (gar, gai), (br, bi), (gbr, gbi))
-    p = rows.shape[-1]
-    wrap = odd[..., None, :] & (jnp.arange(p) == p - 1)
-    return jnp.where(wrap, 0.0, rows)
+def _vrun_columns_bwd(ci_ref, ca_ref, par_ref, dco_ref, l, state, cot):
+    """Reversed stacked-tile sweep of layer ``l``'s mesh: recompute
+    states via the per-cell inverse slab, accumulate each column's
+    coefficient gradient into its slot of the revisited ``dco_ref``,
+    propagate the cotangent via the adjoint slab."""
+    n_cols = ci_ref.shape[3]
 
-
-def _vbwd_column(ci_c, ca_c, par_c, s, g):
-    """One reversed column: reconstruct the column input via the inverse
-    slab, take its coefficient gradient, propagate the cotangent via the
-    adjoint slab — dispatched once per column on the parity code, so
-    uniform columns never pay the mixed path's masking."""
-    def make(step, coef_grad):
-        def branch(sg):
-            s_, g_ = sg[0:4], sg[4:8]
-            s_in = step(ci_c, s_)             # T_c^{-1} s_{c+1}
-            grad = coef_grad(s_in, g_)
-            g_in = step(ca_c, g_)             # T_c^H g_{c+1}
-            return (*s_in, grad, *g_in)
-        return branch
-
-    odd = par_c != 0
-    out = jax.lax.switch(
-        _parity_code(par_c),
-        [make(_vcolumn_even, _vcoef_grad_even),
-         make(_vcolumn_odd, _vcoef_grad_odd),
-         make(lambda cc, st: _vcolumn_mixed(cc, odd, st),
-              lambda s_in, g_: _vcoef_grad_mixed(odd, s_in, g_))],
-        (*s, *g))
-    return out[0:4], out[4], out[5:9]
-
-
-def _vrun_columns_bwd(coef_inv, coef_adj, parity, state, cot):
-    """Reversed stacked-tile sweep: recompute states via the per-cell
-    inverse, accumulate per-column coefficient gradients into a fresh
-    [To, Ti, C, 8, P] value (the caller folds it into the revisited
-    accumulator ref), propagate the cotangent via the adjoint."""
-    n_cols = coef_inv.shape[2]
-    ci = jnp.moveaxis(coef_inv, 2, 0)
-    ca = jnp.moveaxis(coef_adj, 2, 0)
-    par = jnp.moveaxis(parity, 2, 0)
-    dco = jnp.zeros(coef_inv.shape, coef_inv.dtype)
+    def branch(step, coef_grad, ci, ca):
+        def run(sg):
+            s_in = step(ci, sg[0:4])              # T_c^{-1} s_{c+1}
+            rows = coef_grad(s_in, sg[4:8])
+            g_in = step(ca, sg[4:8])              # T_c^H g_{c+1}
+            return (*s_in, *rows, *g_in)
+        return run
 
     def body(k, carry):
         c = n_cols - 1 - k
-        s, g, acc = carry[0:4], carry[4:8], carry[8]
-        s_in, grad, g_in = _vbwd_column(ci[c], ca[c], par[c], s, g)
-        acc = jax.lax.dynamic_update_slice_in_dim(
-            acc, grad[:, :, None], c, axis=2)
-        return (*s_in, *g_in, acc)
+        ci, ca = _coef_rows(ci_ref, l, c), _coef_rows(ca_ref, l, c)
+        out = jax.lax.cond(par_ref[l, c] != 0,
+                           branch(_vcolumn_odd, _vcoef_grad_odd, ci, ca),
+                           branch(_vcolumn_even, _vcoef_grad_even, ci, ca),
+                           carry)
+        for r, row in enumerate(out[4:12]):
+            dco_ref[l, :, :, c, r:r + 1, :] += row
+        return (*out[0:4], *out[12:16])
 
-    out = jax.lax.fori_loop(0, n_cols, body, (*state, *cot, dco))
-    return out[0:4], out[4:8], out[8]
+    out = jax.lax.fori_loop(0, n_cols, body, (*state, *cot))
+    return out[0:4], out[4:8]
 
 
-def _net_layer_stages(coef_v, par_v, coef_u, par_u, g, state):
-    """g0 -> V -> g1 -> U for one stacked layer; returns (v, u) states.
+def _layer_gain_rows(gains_ref, l):
+    """Layer ``l``'s 12 gain rows, [To, Ti, 1, P] each."""
+    return [gains_ref[l, :, :, k:k + 1, :] for k in range(12)]
 
-    ``g`` is the layer's 12 gain planes ([To, Ti, P] each), ``state``
-    the stacked [B, To, Ti, P] input planes."""
+
+def _net_layer_stages(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref, l, g,
+                      state):
+    """g0 -> V -> g1 -> U for stacked layer ``l``; returns (v, u) states.
+
+    ``g`` is the layer's 12 gain rows, ``state`` the stacked
+    [To, Ti, B, P] input planes."""
     er, ei = _cmul(state[0], state[1], g[0], g[1])
     orr, oi = _cmul(state[2], state[3], g[2], g[3])
-    v = _vrun_columns(coef_v, par_v, (er, ei, orr, oi))
+    v = _vrun_columns(coef_v_ref, par_v_ref, l, (er, ei, orr, oi))
     er, ei = _cmul(v[0], v[1], g[4], g[5])
     orr, oi = _cmul(v[2], v[3], g[6], g[7])
-    u = _vrun_columns(coef_u, par_u, (er, ei, orr, oi))
+    u = _vrun_columns(coef_u_ref, par_u_ref, l, (er, ei, orr, oi))
     return v, u
 
 
@@ -799,18 +773,20 @@ def _detect_bwd_z(z, goe, goo):
     return inv_e * zer, inv_e * zei, inv_o * zor, inv_o * zoi
 
 
-def _layer_linear_bwd(cv_inv, cv_adj, par_v, cu_inv, cu_adj, par_u, g,
+def _layer_linear_bwd(cv_inv_ref, cv_adj_ref, par_v_ref, cu_inv_ref,
+                      cu_adj_ref, par_u_ref, dcv_ref, dcu_ref, dg_ref, l, g,
                       x_in, v, u, gz):
-    """Unwind the linear stages g2 -> U -> g1 -> V -> g0 of one stacked
-    layer — every (To, Ti) tile at once.
+    """Unwind the linear stages g2 -> U -> g1 -> V -> g0 of stacked layer
+    ``l`` — every (To, Ti) tile at once.
 
-    ``gz`` is the cotangent of the post-g2 complex state as [B, To, 1, P]
+    ``gz`` is the cotangent of the post-g2 complex state as [To, 1, B, P]
     row planes broadcast to every tile (the row combine is a sum, so each
     tile of a row sees its row's cotangent).  ``x_in``/``v``/``u`` are
-    the stacked layer input and stage states.  Returns the layer's
-    gradient slabs ``(dcv, dcu [To, Ti, C, 8, P], dg [To, Ti, 12, P])``
-    and the per-tile input cotangent planes [B, To, Ti, P] (NOT yet
-    summed over rows — the caller applies the combine's transpose).
+    the stacked layer input and stage states.  Coefficient and gain
+    gradients accumulate into layer ``l``'s slots of the revisited
+    ``dcv_ref``/``dcu_ref``/``dg_ref``; returns the per-tile input
+    cotangent planes [To, Ti, B, P] (NOT yet summed over rows — the
+    caller applies the combine's transpose).
     """
     gzer, gzei, gzor, gzoi = gz
     dg2 = (_vconj_dot(u[0], u[1], gzer, gzei)
@@ -818,16 +794,16 @@ def _layer_linear_bwd(cv_inv, cv_adj, par_v, cu_inv, cu_adj, par_u, g,
     guer, guei = _cmul(g[8], -g[9], gzer, gzei)
     guor, guoi = _cmul(g[10], -g[11], gzor, gzoi)
 
-    _, gh, dcu = _vrun_columns_bwd(cu_inv, cu_adj, par_u, u,
-                                   (guer, guei, guor, guoi))
+    _, gh = _vrun_columns_bwd(cu_inv_ref, cu_adj_ref, par_u_ref, dcu_ref, l,
+                              u, (guer, guei, guor, guoi))
 
     dg1 = (_vconj_dot(v[0], v[1], gh[0], gh[1])
            + _vconj_dot(v[2], v[3], gh[2], gh[3]))
     gver, gvei = _cmul(g[4], -g[5], gh[0], gh[1])
     gvor, gvoi = _cmul(g[6], -g[7], gh[2], gh[3])
 
-    _, gs0, dcv = _vrun_columns_bwd(cv_inv, cv_adj, par_v, v,
-                                    (gver, gvei, gvor, gvoi))
+    _, gs0 = _vrun_columns_bwd(cv_inv_ref, cv_adj_ref, par_v_ref, dcv_ref, l,
+                               v, (gver, gvei, gvor, gvoi))
 
     # pre-gain g0: s0 = g0 * x_in
     dg0 = (_vconj_dot(x_in[0], x_in[1], gs0[0], gs0[1])
@@ -835,38 +811,36 @@ def _layer_linear_bwd(cv_inv, cv_adj, par_v, cu_inv, cu_adj, par_u, g,
     gxer, gxei = _cmul(g[0], -g[1], gs0[0], gs0[1])
     gxor, gxoi = _cmul(g[2], -g[3], gs0[2], gs0[3])
 
-    dg = jnp.stack(list(dg0) + list(dg1) + list(dg2), axis=-2)
-    return dcv, dcu, dg, (gxer, gxei, gxor, gxoi)
-
-
-def _layer_gain_planes(gains_ref, l):
-    """Layer ``l``'s 12 gain planes, [To, Ti, P] each."""
-    g = gains_ref[l]
-    return [g[:, :, k] for k in range(12)]
+    for k, row in enumerate(dg0 + dg1 + dg2):
+        dg_ref[l, :, :, k:k + 1, :] += row
+    return gxer, gxei, gxor, gxoi
 
 
 def _broadcast_tiles(planes, to):
-    """[B, Ti, P] input planes -> stacked [B, To, Ti, P] (every tile row
+    """[Ti, B, P] input planes -> stacked [To, Ti, B, P] (every tile row
     sweeps the whole input), batch-materialized for the fori carries."""
-    b, ti, p = planes[0].shape
-    return tuple(jnp.broadcast_to(t[:, None], (b, to, ti, p))
-                 for t in planes)
+    return tuple(jnp.broadcast_to(t[None], (to,) + t.shape) for t in planes)
+
+
+def _row_combine(z):
+    """Matched-line row combine: each row sums its Ti tile outputs,
+    [To, Ti, B, P] -> [To, B, P]."""
+    return tuple(t.sum(axis=1) for t in z)
 
 
 def _deep_forward(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref, gains_ref,
-                  xer_ref, xei_ref, xor_ref, xoi_ref, stage_refs=None):
+                  x_refs, stage_refs=None):
     """All L layers of the (To x Ti) grid on one batch block, every
-    layer's To*Ti tiles swept together as stacked [B, To, Ti, P] planes.
+    layer's To*Ti tiles swept together as stacked [To, Ti, B, P] planes.
 
-    Input planes are [B, Ti, P]; returns the *last* layer's combined
-    post-g2 row planes ([B, To, P] x 4 — the caller applies the
-    readout).  With ``stage_refs`` (the 8 ``[L, B, To, Ti, P]`` residual
+    Input planes are [Ti, B, P]; returns the *last* layer's combined
+    post-g2 row planes ([To, B, P] x 4 — the caller applies the
+    readout).  With ``stage_refs`` (the 8 ``[L, To, Ti, B, P]`` residual
     refs of the VJP forward) every tile's two pre-gain stage boundaries
     are saved as whole slabs; inference passes ``None``.
     """
     n_layers, to = coef_v_ref.shape[0], coef_v_ref.shape[1]
-    state_in = _broadcast_tiles(
-        (xer_ref[...], xei_ref[...], xor_ref[...], xoi_ref[...]), to)
+    state_in = _broadcast_tiles(tuple(r[...] for r in x_refs), to)
     z_row = None
     for l in range(n_layers):
         if l > 0:
@@ -875,19 +849,23 @@ def _deep_forward(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref, gains_ref,
             oe, oo = _detect_z(z_row)
             zero = jnp.zeros_like(oe)
             state_in = _broadcast_tiles((oe, zero, oo, zero), to)
-        g = _layer_gain_planes(gains_ref, l)
-        v, u = _net_layer_stages(coef_v_ref[l], par_v_ref[l],
-                                 coef_u_ref[l], par_u_ref[l], g, state_in)
+        g = _layer_gain_rows(gains_ref, l)
+        v, u = _net_layer_stages(coef_v_ref, par_v_ref, coef_u_ref,
+                                 par_u_ref, l, g, state_in)
         if stage_refs is not None:
-            (sver, svei, svor, svoi, suer, suei, suor, suoi) = stage_refs
-            sver[l], svei[l] = v[0], v[1]
-            svor[l], svoi[l] = v[2], v[3]
-            suer[l], suei[l] = u[0], u[1]
-            suor[l], suoi[l] = u[2], u[3]
-        z = _tile_z(u, g)
-        # matched-line row combine: sum each row's Ti tile outputs
-        z_row = tuple(t.sum(axis=2) for t in z)
+            for ref, plane in zip(stage_refs, (*v, *u)):
+                ref[l] = plane
+        z_row = _row_combine(_tile_z(u, g))
     return z_row
+
+
+def _write_readout(z, out_refs, detect_last):
+    if detect_last:
+        oe_ref, oo_ref = out_refs
+        oe_ref[...], oo_ref[...] = _detect_z(z)
+    else:
+        for ref, plane in zip(out_refs, z):
+            ref[...] = plane
 
 
 def deepgrid_kernel(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref, gains_ref,
@@ -900,35 +878,20 @@ def deepgrid_kernel(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref, gains_ref,
     states (4 planes).
     """
     z = _deep_forward(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref,
-                      gains_ref, xer_ref, xei_ref, xor_ref, xoi_ref)
-    if detect_last:
-        oe_ref, oo_ref = out_refs
-        oe, oo = _detect_z(z)
-        oe_ref[...], oo_ref[...] = oe, oo
-    else:
-        oer_ref, oei_ref, oor_ref, ooi_ref = out_refs
-        oer_ref[...], oei_ref[...] = z[0], z[1]
-        oor_ref[...], ooi_ref[...] = z[2], z[3]
+                      gains_ref, (xer_ref, xei_ref, xor_ref, xoi_ref))
+    _write_readout(z, out_refs, detect_last)
 
 
 def deepgrid_fwd_kernel(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref,
                         gains_ref, xer_ref, xei_ref, xor_ref, xoi_ref,
                         *out_refs, detect_last: bool):
     """VJP forward: identical sweep, plus every tile's two pre-gain stage
-    boundaries (post-V, post-U) into [L, B, To, Ti, P] residual planes."""
+    boundaries (post-V, post-U) into [L, To, Ti, B, P] residual planes."""
     n_out = 2 if detect_last else 4
-    stage_refs = out_refs[n_out:]
     z = _deep_forward(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref,
-                      gains_ref, xer_ref, xei_ref, xor_ref, xoi_ref,
-                      stage_refs=stage_refs)
-    if detect_last:
-        oe_ref, oo_ref = out_refs[:2]
-        oe, oo = _detect_z(z)
-        oe_ref[...], oo_ref[...] = oe, oo
-    else:
-        oer_ref, oei_ref, oor_ref, ooi_ref = out_refs[:4]
-        oer_ref[...], oei_ref[...] = z[0], z[1]
-        oor_ref[...], ooi_ref[...] = z[2], z[3]
+                      gains_ref, (xer_ref, xei_ref, xor_ref, xoi_ref),
+                      stage_refs=out_refs[n_out:])
+    _write_readout(z, out_refs[:n_out], detect_last)
 
 
 def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
@@ -948,7 +911,7 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
     and crossing a layer boundary re-detects the previous layer's rows
     from their saved post-U states and converts the (real) cotangent
     through the |detect| backward.  Layer 0 writes the input cotangent
-    planes [B, Ti, P].
+    planes [Ti, B, P].
     """
     n_cot = 2 if detect_last else 4
     cot_refs = cot_and_out_refs[:n_cot]
@@ -970,16 +933,16 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
         return (suer_ref[l], suei_ref[l], suor_ref[l], suoi_ref[l])
 
     def row_z(l):
-        """Recompute layer l's combined post-g2 row planes [B, To, P]
+        """Recompute layer l's combined post-g2 row planes [To, B, P]
         from the saved post-U stages (elementwise — no sweep)."""
-        z = _tile_z(saved_u(l), _layer_gain_planes(gains_ref, l))
-        return tuple(t.sum(axis=2) for t in z)
+        return _row_combine(_tile_z(saved_u(l), _layer_gain_rows(gains_ref,
+                                                                 l)))
 
     if detect_last:
         goe_ref, goo_ref = cot_refs
         gz = _detect_bwd_z(row_z(n_layers - 1), goe_ref[...], goo_ref[...])
     else:
-        gz = tuple(r[...] for r in cot_refs)              # [B, To, P]
+        gz = tuple(r[...] for r in cot_refs)              # [To, B, P]
 
     for l in range(n_layers - 1, -1, -1):
         if l == 0:
@@ -995,19 +958,15 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
             zero = jnp.zeros_like(be)
             state_in = _broadcast_tiles((be, zero, bo, zero), to)
         # the row combine is a sum: every tile of a row sees the row's
-        # cotangent ([B, To, 1, P] broadcast across the stacked sweep)
-        gz_t = tuple(t[:, :, None] for t in gz)
-        dcv, dcu, dg, gx = _layer_linear_bwd(
-            cv_inv_ref[l], cv_adj_ref[l], par_v_ref[l],
-            cu_inv_ref[l], cu_adj_ref[l], par_u_ref[l],
-            _layer_gain_planes(gains_ref, l),
-            state_in, saved_v(l), saved_u(l), gz_t)
-        dcv_ref[l] = dcv_ref[l] + dcv
-        dcu_ref[l] = dcu_ref[l] + dcu
-        dg_ref[l] = dg_ref[l] + dg
+        # cotangent ([To, 1, B, P] broadcast across the stacked sweep)
+        gx = _layer_linear_bwd(
+            cv_inv_ref, cv_adj_ref, par_v_ref, cu_inv_ref, cu_adj_ref,
+            par_u_ref, dcv_ref, dcu_ref, dg_ref, l,
+            _layer_gain_rows(gains_ref, l), state_in, saved_v(l),
+            saved_u(l), tuple(t[:, None] for t in gz))
         # combine's transpose: each input tile sums its cotangent over
         # the To rows
-        dx = tuple(t.sum(axis=1) for t in gx)             # [B, Ti, P]
+        dx = tuple(t.sum(axis=0) for t in gx)             # [Ti, B, P]
         if l > 0:
             # boundary crossing keeps only the real cotangent planes (the
             # imaginary planes of an inter-layer input are structurally
@@ -1018,19 +977,14 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
             dxor_ref[...], dxoi_ref[...] = dx[2], dx[3]
 
 
-def _deep_coef_spec(n_layers: int, to: int, ti: int, n_cols: int, p: int):
-    return pl.BlockSpec((n_layers, to, ti, n_cols, 8, p),
-                        lambda b: (0, 0, 0, 0, 0, 0))
+def _resident():
+    """A whole operand held in VMEM for the entire call (one copy)."""
+    return pl.BlockSpec(memory_space=pltpu.VMEM)
 
 
-def _deep_parity_spec(n_layers: int, to: int, ti: int, n_cols: int):
-    return pl.BlockSpec((n_layers, to, ti, n_cols, 1),
-                        lambda b: (0, 0, 0, 0, 0))
-
-
-def _deep_gains_spec(n_layers: int, to: int, ti: int, p: int):
-    return pl.BlockSpec((n_layers, to, ti, 12, p),
-                        lambda b: (0, 0, 0, 0, 0))
+def _smem():
+    """A whole (parity table) operand in scalar memory."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _deep_flops_per_block(n: int, n_layers: int, to: int, ti: int,
@@ -1045,27 +999,44 @@ def _deep_coef_bytes(n_layers: int, to: int, ti: int, n_cols: int,
     return n_layers * to * ti * (n_cols * 8 + 12) * p * 4
 
 
+def _deep_shapes(n, n_layers, to, ti, n_cols, batch_block):
+    """Shapes of the deep call's operand classes: coefficient stack, gain
+    stack, and the per-block x / output / stage planes."""
+    p = n // 2
+    return ((n_layers, to, ti, n_cols, 8, p), (n_layers, to, ti, 12, p),
+            (ti, batch_block, p), (to, batch_block, p),
+            (n_layers, to, ti, batch_block, p))
+
+
+def _deep_plane_specs(n, n_layers, to, ti, batch_block):
+    p = n // 2
+    x_plane = pl.BlockSpec((ti, batch_block, p), lambda b: (0, b, 0))
+    o_plane = pl.BlockSpec((to, batch_block, p), lambda b: (0, b, 0))
+    stage = pl.BlockSpec((n_layers, to, ti, batch_block, p),
+                         lambda b: (0, 0, 0, b, 0))
+    return x_plane, o_plane, stage
+
+
 def deepgrid_pallas_call(n: int, n_layers: int, to: int, ti: int,
                          n_cols: int, batch_block: int, n_batch_blocks: int,
                          detect_last: bool, interpret: bool):
     p = n // 2
     b_total = n_batch_blocks * batch_block
-    x_plane = pl.BlockSpec((batch_block, ti, p), lambda b: (b, 0, 0))
-    o_plane = pl.BlockSpec((batch_block, to, p), lambda b: (b, 0, 0))
+    coef, gains, xb, ob, _ = _deep_shapes(n, n_layers, to, ti, n_cols,
+                                          batch_block)
+    x_plane, o_plane, _ = _deep_plane_specs(n, n_layers, to, ti, batch_block)
     n_out = 2 if detect_last else 4
-    out_shape = [jax.ShapeDtypeStruct((b_total, to, p), jnp.float32)] * n_out
+    out_shape = [jax.ShapeDtypeStruct((to, b_total, p), jnp.float32)] * n_out
     flops = _deep_flops_per_block(n, n_layers, to, ti, n_cols, batch_block)
     return pl.pallas_call(
         functools.partial(deepgrid_kernel, detect_last=detect_last),
         grid=(n_batch_blocks,),
-        in_specs=[_deep_coef_spec(n_layers, to, ti, n_cols, p),
-                  _deep_parity_spec(n_layers, to, ti, n_cols),
-                  _deep_coef_spec(n_layers, to, ti, n_cols, p),
-                  _deep_parity_spec(n_layers, to, ti, n_cols),
-                  _deep_gains_spec(n_layers, to, ti, p),
+        in_specs=[_resident(), _smem(), _resident(), _smem(), _resident(),
                   x_plane, x_plane, x_plane, x_plane],
         out_specs=[o_plane] * n_out,
         out_shape=out_shape,
+        compiler_params=_deep_compiler_params(
+            [coef, coef, gains], [xb] * 4 + [ob] * n_out),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=flops * n_batch_blocks,
@@ -1084,27 +1055,25 @@ def deepgrid_fwd_pallas_call(n: int, n_layers: int, to: int, ti: int,
                              interpret: bool):
     p = n // 2
     b_total = n_batch_blocks * batch_block
-    x_plane = pl.BlockSpec((batch_block, ti, p), lambda b: (b, 0, 0))
-    o_plane = pl.BlockSpec((batch_block, to, p), lambda b: (b, 0, 0))
-    stage = pl.BlockSpec((n_layers, batch_block, to, ti, p),
-                         lambda b: (0, b, 0, 0, 0))
+    coef, gains, xb, ob, sb = _deep_shapes(n, n_layers, to, ti, n_cols,
+                                           batch_block)
+    x_plane, o_plane, stage = _deep_plane_specs(n, n_layers, to, ti,
+                                                batch_block)
     n_out = 2 if detect_last else 4
     out_shape = (
-        [jax.ShapeDtypeStruct((b_total, to, p), jnp.float32)] * n_out
-        + [jax.ShapeDtypeStruct((n_layers, b_total, to, ti, p),
+        [jax.ShapeDtypeStruct((to, b_total, p), jnp.float32)] * n_out
+        + [jax.ShapeDtypeStruct((n_layers, to, ti, b_total, p),
                                 jnp.float32)] * 8)
     flops = _deep_flops_per_block(n, n_layers, to, ti, n_cols, batch_block)
     return pl.pallas_call(
         functools.partial(deepgrid_fwd_kernel, detect_last=detect_last),
         grid=(n_batch_blocks,),
-        in_specs=[_deep_coef_spec(n_layers, to, ti, n_cols, p),
-                  _deep_parity_spec(n_layers, to, ti, n_cols),
-                  _deep_coef_spec(n_layers, to, ti, n_cols, p),
-                  _deep_parity_spec(n_layers, to, ti, n_cols),
-                  _deep_gains_spec(n_layers, to, ti, p),
+        in_specs=[_resident(), _smem(), _resident(), _smem(), _resident(),
                   x_plane, x_plane, x_plane, x_plane],
         out_specs=[o_plane] * n_out + [stage] * 8,
         out_shape=out_shape,
+        compiler_params=_deep_compiler_params(
+            [coef, coef, gains], [xb] * 4 + [ob] * n_out + [sb] * 8),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=flops * n_batch_blocks,
@@ -1124,32 +1093,29 @@ def deepgrid_bwd_pallas_call(n: int, n_layers: int, to: int, ti: int,
                              interpret: bool):
     p = n // 2
     b_total = n_batch_blocks * batch_block
-    x_plane = pl.BlockSpec((batch_block, ti, p), lambda b: (b, 0, 0))
-    o_plane = pl.BlockSpec((batch_block, to, p), lambda b: (b, 0, 0))
-    stage = pl.BlockSpec((n_layers, batch_block, to, ti, p),
-                         lambda b: (0, b, 0, 0, 0))
+    coef, gains, xb, ob, sb = _deep_shapes(n, n_layers, to, ti, n_cols,
+                                           batch_block)
+    x_plane, o_plane, stage = _deep_plane_specs(n, n_layers, to, ti,
+                                                batch_block)
     n_cot = 2 if detect_last else 4
     out_shape = (
-        [jax.ShapeDtypeStruct((n_layers, to, ti, n_cols, 8, p),
-                              jnp.float32)] * 2
-        + [jax.ShapeDtypeStruct((n_layers, to, ti, 12, p), jnp.float32)]
-        + [jax.ShapeDtypeStruct((b_total, ti, p), jnp.float32)] * 4)
+        [jax.ShapeDtypeStruct(coef, jnp.float32)] * 2
+        + [jax.ShapeDtypeStruct(gains, jnp.float32)]
+        + [jax.ShapeDtypeStruct((ti, b_total, p), jnp.float32)] * 4)
     # inverse state recompute + adjoint cotangent + coefficient grads
     flops = 3 * _deep_flops_per_block(n, n_layers, to, ti, n_cols,
                                       batch_block)
     return pl.pallas_call(
         functools.partial(deepgrid_bwd_kernel, detect_last=detect_last),
         grid=(n_batch_blocks,),
-        in_specs=[_deep_coef_spec(n_layers, to, ti, n_cols, p)] * 2
-        + [_deep_parity_spec(n_layers, to, ti, n_cols)]
-        + [_deep_coef_spec(n_layers, to, ti, n_cols, p)] * 2
-        + [_deep_parity_spec(n_layers, to, ti, n_cols),
-           _deep_gains_spec(n_layers, to, ti, p),
-           x_plane, x_plane, x_plane, x_plane]
-        + [stage] * 8 + [o_plane] * n_cot,
-        out_specs=[_deep_coef_spec(n_layers, to, ti, n_cols, p)] * 2
-        + [_deep_gains_spec(n_layers, to, ti, p)] + [x_plane] * 4,
+        in_specs=[_resident(), _resident(), _smem(),
+                  _resident(), _resident(), _smem(), _resident()]
+        + [x_plane] * 4 + [stage] * 8 + [o_plane] * n_cot,
+        out_specs=[_resident()] * 3 + [x_plane] * 4,
         out_shape=out_shape,
+        compiler_params=_deep_compiler_params(
+            [coef] * 6 + [gains] * 2,
+            [xb] * 8 + [sb] * 8 + [ob] * n_cot),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=flops * n_batch_blocks,

@@ -1,7 +1,8 @@
 """Jitted, differentiable public wrappers around the Pallas mesh kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites run in this
-CPU container (kernel body executed op-by-op) and compile to Mosaic on TPU.
+``interpret`` defaults to :func:`default_interpret`: the kernels compile
+to Mosaic on a TPU and run in the Pallas interpreter on the CPU (tests,
+local runs), from the same call sites.
 
 Both ``mesh_apply`` and ``rfnn_linear`` carry custom VJPs: the backward
 pass is itself a fused Pallas kernel that re-runs the mesh columns in
@@ -26,6 +27,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import hardware as hw_lib
 from repro.core import mesh as mesh_lib
@@ -33,11 +35,12 @@ from repro.core.cell import cell_matrix
 from repro.kernels import givens_mesh, ref
 from repro.kernels.schedule import (
     MeshSchedule,
+    align_columns,
     clements_schedule,
+    deep_grid_column_sources,
     deep_grid_parity_arrays,
     deep_grid_schedule,
     pack_cells,
-    pad_columns,
     parity_array,
     schedule_from_plan,
 )
@@ -67,8 +70,16 @@ TRACE_COUNTS = {"mesh_apply": 0, "rfnn_linear": 0, "rfnn_network": 0,
 PACK_EVENTS = {"rfnn_network": 0, "tiled_apply": 0, "deep_apply": 0}
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def default_interpret() -> bool:
+    """Compiled kernels on the TPU, the Pallas interpreter on the CPU
+    (tests and local runs); any other backend is an error rather than a
+    silent interpreted fallback."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for TPU and interpret on CPU; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 def _auto_block(b: int, block_b: int) -> int:
@@ -241,7 +252,7 @@ def mesh_apply(params: dict, x: Array, *, n: int,
     Differentiable w.r.t. ``params`` and ``x`` through the kernel VJP.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     sched = clements_schedule(n) if plan is None else schedule_from_plan(plan)
     KERNEL_PATH_CALLS["mesh_apply"] += 1
     return _mesh_apply_impl(sched, hardware, block_b, interpret,
@@ -274,7 +285,7 @@ def mesh_apply_cells(t_all: Array, x: Array, *, plan: mesh_lib.MeshPlan,
     through (theta, phi).  ``vmap``-compatible over ``t_all`` and ``x``.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     sched = schedule_from_plan(plan)
     KERNEL_PATH_CALLS["mesh_apply_cells"] += 1
     return _mesh_apply_cells_impl(sched, block_b, interpret, t_all, x,
@@ -342,7 +353,7 @@ def rfnn_linear(v_params: dict, atten: Array, u_params: dict, x: Array, *,
     and ``x`` through the fused kernel VJP.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     sched_v = (clements_schedule(n) if v_plan is None
                else schedule_from_plan(v_plan))
     sched_u = (clements_schedule(n) if u_plan is None
@@ -439,31 +450,50 @@ _deepgrid_planes.defvjp(_deepgrid_planes_fwd, _deepgrid_planes_bwd)
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _pack_deep_grid_impl(deep, hardware, layers):
     """Stacked [L, To, Ti, C, 8, P] coefficients + [L, To, Ti, 12, P]
-    gains for the deep megakernel, identity-padded to the network-wide
-    column count.  Per-tile gains use the layer layout (g0 input screens,
-    g1 attenuation + folded mid screens, g2 digital scale + output
-    screen)."""
-    c = deep.n_columns
-    coef_v, coef_u, gains = [], [], []
-    for slayer, tlayer in zip(deep.layers, layers):
-        cv_l, cu_l, g_l = [], [], []
-        for srow, trow in zip(slayer, tlayer):
-            cv_row, cu_row, g_row = [], [], []
-            for (sv, su), ta in zip(srow, trow):
-                cv_row.append(pad_columns(
-                    _mesh_coefficients(sv, ta["v"], hardware,
-                                       ta.get("key_v")), c))
-                cu_row.append(pad_columns(
-                    _mesh_coefficients(su, ta["u"], hardware,
-                                       ta.get("key_u")), c))
-                g_row.append(_layer_gains(deep.n, ta))
-            cv_l.append(jnp.stack(cv_row))
-            cu_l.append(jnp.stack(cu_row))
-            g_l.append(jnp.stack(g_row))
-        coef_v.append(jnp.stack(cv_l))
-        coef_u.append(jnp.stack(cu_l))
-        gains.append(jnp.stack(g_l))
-    return (jnp.stack(coef_v), jnp.stack(coef_u), jnp.stack(gains))
+    gains for the deep megakernel, each tile's columns gathered onto the
+    aligned kernel columns (identity cells where it sits a column out
+    and in the padding).  Per-tile gains use the layer layout (g0 input
+    screens, g1 attenuation + folded mid screens, g2 digital scale +
+    output screen)."""
+    src = deep_grid_column_sources(deep)               # [2, L, To, Ti, C]
+    # Tiles that share their schedules, column alignment and argument
+    # structure pack as ONE vmapped computation, so the traced program
+    # (and the chip compiler's time on it) grows with the number of
+    # distinct tile kinds rather than with L * To * Ti.
+    groups: dict = {}
+    for l, o, i in np.ndindex(deep.n_layers, deep.to, deep.ti):
+        ta = layers[l][o][i]
+        kind = (deep.layers[l][o][i], src[0, l, o, i].tobytes(),
+                src[1, l, o, i].tobytes(), jax.tree.structure(ta))
+        groups.setdefault(kind, []).append((l, o, i))
+    order, outs = [], []
+    for positions in groups.values():
+        l0, o0, i0 = positions[0]
+        sv, su = deep.layers[l0][o0][i0]
+
+        def one(ta, sv=sv, su=su, sv_src=src[0, l0, o0, i0],
+                su_src=src[1, l0, o0, i0]):
+            return (align_columns(_mesh_coefficients(
+                        sv, ta["v"], hardware, ta.get("key_v")), sv_src),
+                    align_columns(_mesh_coefficients(
+                        su, ta["u"], hardware, ta.get("key_u")), su_src),
+                    _layer_gains(deep.n, ta))
+
+        stacked = jax.tree.map(lambda *a: jnp.stack(a),
+                               *(layers[l][o][i] for l, o, i in positions))
+        outs.append(jax.vmap(one)(stacked))
+        order += [np.ravel_multi_index(p, (deep.n_layers, deep.to, deep.ti))
+                  for p in positions]
+    lead = (deep.n_layers, deep.to, deep.ti)
+    inverse = np.argsort(order)
+
+    def place(parts):
+        flat = jnp.concatenate(parts)
+        if order != sorted(order):
+            flat = jnp.take(flat, jnp.asarray(inverse), axis=0)
+        return flat.reshape(lead + flat.shape[1:])
+
+    return tuple(place(parts) for parts in zip(*outs))
 
 
 def _contains_tracer(tree) -> bool:
@@ -556,30 +586,30 @@ def pack_deep_grid(layers, *, n: int, plans=None,
         (deep, hardware), layers, build)
 
 
-#: VMEM working-set target for the fused sweeps (well under the ~16
-#: MB/core budget: the backward also holds 2 coefficient tensors per mesh
-#: plus the gradient accumulators).
+#: Working-set target for one buffer of a call's batch-blocked planes
+#: (the pipeline double-buffers them; the resident coefficient tensors
+#: and the limit they need are counted by the kernel builders).
 _VMEM_TARGET = 4 * 1024 * 1024
 
 
 def _vmem_auto_block(b: int, block_b: int | None, n: int,
-                     planes_per_row: int) -> int:
+                     planes_per_row: int, interpret: bool) -> int:
     """The one VMEM-budget batch-block helper (every kernel's auto-block
     is this function with its own plane count).
 
-    ``None`` sizes the block so ``planes_per_row`` resident [block, P]
-    f32 planes fit the VMEM target — small problems get large blocks
-    (fewer grid revisits of the coefficient accumulators), deep/wide
-    ones shrink toward the classic 128 — then shrinks for small batches.
-
-    The same target applies in interpret mode: the stacked-sweep bodies
-    make grid steps cheap (one fori_loop per mesh regardless of the tile
-    count), and a VMEM-sized block is also a host-cache-sized block, so
-    a deep grid's batch blocks ride through all L layers while hot —
-    the locality the fused kernel exists to buy.
+    ``None`` sizes the block so ``planes_per_row`` [block, P] f32 planes
+    fit the target, then shrinks for small batches.  On the chip a
+    plane's P lanes occupy whole 128-lane tiles (see
+    ``givens_mesh.vmem_bytes``), so a 16-channel tile's 8-lane plane
+    costs 16x its data and deep grids get blocks of a few sublane rows.
+    The interpreter has no tiled VMEM: there the data bytes set the
+    block, and a block that fits a host cache rides through all L
+    layers while hot.
     """
     if block_b is None:
-        per_row = planes_per_row * (n // 2) * 4
+        p = n // 2
+        lanes = p if interpret else -(-p // 128) * 128
+        per_row = planes_per_row * lanes * 4
         block_b = max(8, min(1024, _VMEM_TARGET // per_row // 8 * 8))
     return _auto_block(b, block_b)
 
@@ -594,7 +624,9 @@ def _deep_planes_per_row(deep) -> int:
 
 
 def _split_tile_planes(xt):
-    """[B, Ti, n] complex -> 4 de-interleaved [B, Ti, P] f32 planes."""
+    """[B, Ti, n] complex -> 4 de-interleaved [Ti, B, P] f32 planes (the
+    kernel's tile-major layout)."""
+    xt = jnp.swapaxes(xt, 0, 1)
     xe, xo = xt[..., 0::2], xt[..., 1::2]
     return (jnp.real(xe).astype(jnp.float32),
             jnp.imag(xe).astype(jnp.float32),
@@ -603,13 +635,15 @@ def _split_tile_planes(xt):
 
 
 def _merge_deep_out(outs, detect_last, to, n, b_orig, batch_shape):
-    """Kernel output planes -> [..., To*n] (real magnitudes or complex)."""
+    """Kernel output planes [To, B, P] -> [..., To*n] (real magnitudes or
+    complex)."""
+    outs = [jnp.swapaxes(t, 0, 1) for t in outs]   # [B, To, P]
     if detect_last:
-        oe, oo = outs                              # [B, To, P] real
+        oe, oo = outs
         y = jnp.stack([oe, oo], axis=-1).reshape((-1, to * n))[:b_orig]
         return y.reshape(batch_shape + (to * n,))
     oer, oei, oor, ooi = outs
-    ye = oer + 1j * oei                            # [B, To, P]
+    ye = oer + 1j * oei
     yo = oor + 1j * ooi
     y = jnp.stack([ye, yo], axis=-1).reshape((-1, to * n))[:b_orig]
     return y.astype(jnp.complex64).reshape(batch_shape + (to * n,))
@@ -623,7 +657,7 @@ def _deep_apply_impl(deep, block_b, interpret, detect_last, trace_key,
     batch_shape = x.shape[:-1]
     xt = x.reshape((-1, ti, n)).astype(jnp.complex64)
     bb = _vmem_auto_block(xt.shape[0], block_b, n,
-                          _deep_planes_per_row(deep))
+                          _deep_planes_per_row(deep), interpret)
     xt, b_orig = _pad_batch(xt, bb)
     nb = xt.shape[0] // bb
     outs = _deepgrid_planes(deep, bb, nb, interpret, detect_last,
@@ -701,7 +735,7 @@ def _deepgrid_planes_sharded(deep, layer, mesh, row_axis, data_axis,
         call = givens_mesh.deepgrid_pallas_call(
             deep.n, 1, to_local, deep.ti, deep.n_columns, block_b, nb,
             detect_last, interpret)
-        return tuple(call(sl(cv), sl(pv), sl(cu), sl(pu), sl(g),
+        return tuple(call(sl(cv), pv, sl(cu), pu, sl(g),
                           xer, xei, xor, xoi))
 
     n_out = 2 if detect_last else 4
@@ -724,7 +758,7 @@ def _deepgrid_planes_sharded_fwd(deep, layer, mesh, row_axis, data_axis,
         call = givens_mesh.deepgrid_fwd_pallas_call(
             deep.n, 1, to_local, deep.ti, deep.n_columns, block_b, nb,
             detect_last, interpret)
-        return tuple(call(sl(cv), sl(pv), sl(cu), sl(pu), sl(g),
+        return tuple(call(sl(cv), pv, sl(cu), pu, sl(g),
                           xer, xei, xor, xoi))
 
     n_out = 2 if detect_last else 4
@@ -750,7 +784,7 @@ def _deepgrid_planes_sharded_bwd(deep, layer, mesh, row_axis, data_axis,
 
     def body(cv, pv, cu, pu, g, xer, xei, xor, xoi, *rest):
         sl = _row_slab(row_axis, to_local, 1)
-        cv, pv, cu, pu, g = sl(cv), sl(pv), sl(cu), sl(pu), sl(g)
+        cv, cu, g = sl(cv), sl(cu), sl(g)
         call = givens_mesh.deepgrid_bwd_pallas_call(
             deep.n, 1, to_local, deep.ti, deep.n_columns, block_b, nb,
             detect_last, interpret)
@@ -801,7 +835,7 @@ def _deep_apply_sharded_impl(deep, mesh, row_axis, data_axis, block_b,
     xt = x.reshape((-1, ti, n)).astype(jnp.complex64)
     n_data = mesh.shape[data_axis]
     bb = _vmem_auto_block(max(1, -(-xt.shape[0] // n_data)), block_b, n,
-                          _deep_planes_per_row(deep))
+                          _deep_planes_per_row(deep), interpret)
     # every device's batch shard must tile into whole blocks
     xt, b_orig = _pad_batch(xt, bb * n_data)
     nb = xt.shape[0] // n_data // bb
@@ -870,7 +904,7 @@ def deep_apply(layers, x: Array, *, n: int, plans=None,
     ``To % mesh.shape[row_axis] == 0``.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     if readout not in ("magnitude", "complex"):
         raise ValueError(f"readout must be 'magnitude' or 'complex', "
                          f"got {readout!r}")

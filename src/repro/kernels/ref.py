@@ -8,10 +8,13 @@ the test suite (two independent implementations of the same physics).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import hardware as hw_lib
 from repro.core import mesh as mesh_lib
 from repro.core.cell import cell_matrix
 
@@ -122,6 +125,53 @@ def rfnn_linear_ref(v_params: dict, atten: Array, u_params: dict, x: Array,
     h = h * atten.astype(jnp.complex64)
     y = mesh_apply_ref(u_params, h, n)
     return jnp.abs(scale * y)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _mesh_ref(plan, hardware, params, x, key):
+    if hardware is None:
+        return mesh_lib.apply_mesh(plan, params, x)
+    return hw_lib.apply_mesh_hw(plan, params, x, hardware, key)
+
+
+def deep_apply_ref(layers, x: Array, *, n: int, plans=None,
+                   hardware: hw_lib.HardwareModel | None = None,
+                   readout: str = "magnitude") -> Array:
+    """Pure-jnp oracle of :func:`repro.kernels.ops.deep_apply`.
+
+    Every tile of every layer propagates through the reference mesh
+    (:func:`repro.core.mesh.apply_mesh`, or with ``hardware``
+    :func:`repro.core.hardware.apply_mesh_hw` with the tile's own
+    ``key_v``/``key_u``): ``z = scale * U(atten * V(x_i))``.  Each row
+    sums its tiles' outputs, and ``|.|`` detects between layers and,
+    with the ``"magnitude"`` readout, after the last.  ``layers`` and
+    ``plans`` are nested ``[L][To][Ti]`` as for ``deep_apply``.
+    Each mesh is one jitted call keyed on its plan, so an eager
+    evaluation compiles one small program per distinct plan and reuses it
+    across every tile.
+    """
+    def mesh(plan, params, v, key):
+        return _mesh_ref(plan, hardware, params, v, key)
+
+    h = jnp.asarray(x).astype(jnp.complex64)
+    for l, grid in enumerate(layers):
+        rows = []
+        for o, row in enumerate(grid):
+            z = 0.0
+            for i, ta in enumerate(row):
+                pair = None if plans is None else plans[l][o][i]
+                v_plan, u_plan = [
+                    mesh_lib.clements_plan(n) if p is None else p
+                    for p in ((None, None) if pair is None else pair)]
+                v = mesh(v_plan, ta["v"], h[..., i * n:(i + 1) * n],
+                         ta.get("key_v"))
+                u = mesh(u_plan, ta["u"], ta["atten"] * v, ta.get("key_u"))
+                z = z + jnp.asarray(ta.get("scale", 1.0), jnp.complex64) * u
+            rows.append(z)
+        h = jnp.concatenate(rows, axis=-1)
+        if l < len(layers) - 1 or readout == "magnitude":
+            h = jnp.abs(h)
+    return h
 
 
 def flash_attention_ref(q: Array, k: Array, v: Array,

@@ -167,8 +167,9 @@ def pack_cells(sched: MeshSchedule, t_all: Array) -> Array:
 # and tile-grid (1 x To x Ti) schedules are its degenerate cases.
 # ---------------------------------------------------------------------------
 
-#: Coefficient rows of an identity 2x2 cell (t00 = t11 = 1): the padding
-#: column appended to short layers so every layer shares one column count.
+#: Coefficient rows of an identity 2x2 cell (t00 = t11 = 1): the cell a
+#: tile holds in a kernel column it sits out, and the padding column of
+#: short meshes, so every mesh shares one column count.
 _IDENTITY_ROWS = (1.0, 0.0, 0.0, 0.0, 0.0, 0.0, 1.0, 0.0)
 
 
@@ -178,15 +179,15 @@ class DeepGridSchedule:
 
     ``layers[l][o][i]`` is the ``(V, U)`` pair of :class:`MeshSchedule`\\ s
     of tile ``(o, i)`` in layer ``l``.  The deep megakernel runs the whole
-    network in one VMEM residency with coefficient/parity/gain tensors
-    stacked to ``[L, To, Ti, C, 8, P]`` / ``[L, To, Ti, C, 1]`` /
-    ``[L, To, Ti, 12, P]``, where ``C = n_columns`` is the max column
-    count over every mesh in the network (shorter meshes pad with
-    identity columns — exact no-ops in the sweep).  Between layers the
-    kernel re-detects the combined row outputs in VMEM, so layer ``l``'s
-    ``To`` rows feed layer ``l+1``'s ``Ti`` input tiles without touching
-    HBM; chaining under one uniform stacked tensor therefore requires
-    ``To == Ti`` whenever ``L > 1``.  Hashable and purely static — a
+    network in one VMEM residency with coefficient/gain tensors stacked
+    to ``[L, To, Ti, C, 8, P]`` / ``[L, To, Ti, 12, P]`` and one ``[L, C]``
+    parity table per mesh, where ``C = n_columns`` counts the columns of
+    every layer's tile schedules aligned to shared parities (identity
+    cells fill a tile's sat-out and padding columns — exact no-ops in
+    the sweep).  Between layers the kernel re-detects the combined row
+    outputs in VMEM, so layer ``l``'s ``To`` rows feed layer ``l+1``'s
+    ``Ti`` input tiles without touching HBM; chaining under one uniform
+    stacked tensor therefore requires ``To == Ti`` whenever ``L > 1``.  Hashable and purely static — a
     jit/static and ``custom_vjp`` nondiff argument like
     :class:`MeshSchedule`.
     """
@@ -241,13 +242,10 @@ class DeepGridSchedule:
 
     @property
     def n_columns(self) -> int:
-        return max(max(sv.n_columns, su.n_columns)
-                   for grid in self.layers for row in grid for sv, su in row)
-
-    def layer(self, l: int) -> "DeepGridSchedule":
-        """The single-layer (1 x To x Ti) schedule of layer ``l`` — the
-        row-sharded deep path runs one such slice per pallas call."""
-        return DeepGridSchedule(layers=(self.layers[l],))
+        """Kernel column count: every layer's tile schedules aligned to
+        shared per-column parities (see :func:`deep_grid_parity_arrays`),
+        padded to the longest mesh in the network."""
+        return _deep_grid_columns_np(self)[0].shape[-1]
 
 
 def deep_grid_schedule(n: int, depth: int, to: int, ti: int,
@@ -283,31 +281,76 @@ def deep_grid_schedule(n: int, depth: int, to: int, ti: int,
     return DeepGridSchedule(layers=tuple(layers))
 
 
+def _align_parities(seqs: list[tuple[int, ...]]) -> tuple[list[int],
+                                                            list[list[int]]]:
+    """Merge per-tile column parity sequences into one shared sequence.
+
+    Tiles are independent meshes, so a tile may sit out any column as an
+    identity cell.  Walking every tile's pending column, a column on
+    which all pending tiles agree is taken by all of them; where they
+    disagree, the parity of the tile with the most columns left goes
+    first (it bounds the merged length) and the others wait.  Returns
+    the merged parities and, per tile, the source column of each merged
+    column (-1 where the tile sits out).
+    """
+    ptr = [0] * len(seqs)
+    parity: list[int] = []
+    sources: list[list[int]] = [[] for _ in seqs]
+    while True:
+        pending = [(len(s) - p, s[p]) for s, p in zip(seqs, ptr) if p < len(s)]
+        if not pending:
+            return parity, sources
+        q = max(pending)[1] if len({par for _, par in pending}) > 1 \
+            else pending[0][1]
+        parity.append(q)
+        for t, s in enumerate(seqs):
+            if ptr[t] < len(s) and s[ptr[t]] == q:
+                sources[t].append(ptr[t])
+                ptr[t] += 1
+            else:
+                sources[t].append(-1)
+
+
 @functools.lru_cache(maxsize=64)
-def _deep_grid_parity_np(deep: DeepGridSchedule) -> tuple[np.ndarray,
-                                                          np.ndarray]:
-    c = deep.n_columns
-    shape = (deep.n_layers, deep.to, deep.ti, c, 1)
-    pv = np.zeros(shape, np.int32)
-    pu = np.zeros(shape, np.int32)
-    for l, grid in enumerate(deep.layers):
-        for o, row in enumerate(grid):
-            for i, (sv, su) in enumerate(row):
-                pv[l, o, i, : sv.n_columns, 0] = sv.parity
-                pu[l, o, i, : su.n_columns, 0] = su.parity
-    return pv, pu
+def _deep_grid_columns_np(deep: DeepGridSchedule) -> tuple[np.ndarray,
+                                                           np.ndarray]:
+    """Per-column parity ``[2, L, C]`` and per-tile source column
+    ``[2, L, To, Ti, C]`` (mesh axis 0 = V, 1 = U) of the aligned deep
+    schedule, identity-padded to the network-wide column count."""
+    merged = []
+    for grid in deep.layers:
+        for m in (0, 1):
+            merged.append(_align_parities(
+                [row[i][m].parity for row in grid for i in range(deep.ti)]))
+    c = max(len(par) for par, _ in merged)
+    parity = np.zeros((2, deep.n_layers, c), np.int32)
+    source = np.full((2, deep.n_layers, deep.to * deep.ti, c), -1, np.int64)
+    for k, (par, src) in enumerate(merged):
+        l, m = divmod(k, 2)
+        parity[m, l, : len(par)] = par
+        source[m, l, :, : len(par)] = src
+    return parity, source.reshape(2, deep.n_layers, deep.to, deep.ti, c)
 
 
 def deep_grid_parity_arrays(deep: DeepGridSchedule) -> tuple[Array, Array]:
-    """Stacked ``[L, To, Ti, C, 1]`` int32 parity inputs for the V/U meshes.
+    """``[L, C]`` int32 column parities of the V and U meshes.
 
-    Identity-padded columns get parity 0 (their coefficient is the
-    identity cell, so the pairing is irrelevant).  Host-side build is
-    memoized per schedule (numpy, nothing trace-local cached), keyed by
-    content — structurally equal deep grids share it.
+    Every tile of a layer shares each column's parity: tiles whose own
+    schedules disagree on a column take it as two columns, each tile
+    passing the other's as an identity cell (see
+    :func:`deep_grid_column_sources`).  Identity-padded columns get
+    parity 0.  Host-side build is memoized per schedule (numpy, nothing
+    trace-local cached), keyed by content — structurally equal deep grids
+    share it.
     """
-    pv, pu = _deep_grid_parity_np(deep)
-    return jnp.asarray(pv), jnp.asarray(pu)
+    parity, _ = _deep_grid_columns_np(deep)
+    return jnp.asarray(parity[0]), jnp.asarray(parity[1])
+
+
+def deep_grid_column_sources(deep: DeepGridSchedule) -> np.ndarray:
+    """``[2, L, To, Ti, C]`` source column of each tile's mesh (V, U) for
+    every aligned kernel column; -1 marks an identity cell."""
+    return _deep_grid_columns_np(deep)[1]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -316,12 +359,11 @@ class NetworkSchedule:
 
     Each layer is a ``(V, U)`` pair of :class:`MeshSchedule`\\ s over the
     same channel count; the kernel runs all layers in one VMEM residency
-    with coefficient/parity tensors stacked to ``[L, C, 8, P]`` /
-    ``[L, C, 1]``, where ``C = n_columns`` is the max column count over
-    every mesh (shorter meshes are padded with identity columns, which the
-    sweep applies as exact no-ops).  Hashable and purely static, so it is
-    a jit/static and ``custom_vjp`` nondiff argument like
-    :class:`MeshSchedule`.
+    as the L x 1 x 1 :attr:`deep` schedule, where ``C = n_columns`` is the
+    max column count over every mesh (shorter meshes are padded with
+    identity columns, which the sweep applies as exact no-ops).  Hashable
+    and purely static, so it is a jit/static and ``custom_vjp`` nondiff
+    argument like :class:`MeshSchedule`.
     """
 
     layers: tuple[tuple[MeshSchedule, MeshSchedule], ...]
@@ -382,29 +424,6 @@ def network_schedule(n: int, depth: int,
     return NetworkSchedule(layers=tuple(layers))
 
 
-@functools.lru_cache(maxsize=64)
-def _network_parity_np(net: NetworkSchedule) -> tuple[np.ndarray, np.ndarray]:
-    c = net.n_columns
-    pv = np.zeros((net.n_layers, c, 1), np.int32)
-    pu = np.zeros((net.n_layers, c, 1), np.int32)
-    for l, (sv, su) in enumerate(net.layers):
-        pv[l, : sv.n_columns, 0] = sv.parity
-        pu[l, : su.n_columns, 0] = su.parity
-    return pv, pu
-
-
-def network_parity_arrays(net: NetworkSchedule) -> tuple[Array, Array]:
-    """Stacked ``[L, C, 1]`` int32 parity inputs for the V and U meshes.
-
-    Identity-padded columns get parity 0 (the padding coefficient is the
-    identity cell, so the pairing is irrelevant).  The host-side build is
-    memoized per schedule (numpy, so nothing trace-local is cached):
-    steady-state steps rebuild nothing host-side.
-    """
-    pv, pu = _network_parity_np(net)
-    return jnp.asarray(pv), jnp.asarray(pu)
-
-
 # ---------------------------------------------------------------------------
 # Tile-grid schedules: a (To x Ti) grid of per-tile (V, U) schedules for the
 # tile-grid megakernel (one pallas_call for a large blocked matmul)
@@ -421,11 +440,11 @@ class TileGridSchedule:
     every input tile is swept through its meshes and the row's outputs are
     coherently summed in VMEM (the matched-line power-combiner), so a
     ``(To*n) x (Ti*n)`` matmul is one ``pallas_call`` instead of ``To*Ti``
-    separate mesh applications.  Coefficient/parity tensors are stacked to
-    ``[To, Ti, C, 8, P]`` / ``[To, Ti, C, 1]`` with ``C`` the max column
-    count over every mesh in the grid (identity-column padding, exact
-    no-ops in the sweep).  Hashable and purely static — a jit/static and
-    ``custom_vjp`` nondiff argument like :class:`NetworkSchedule`.
+    separate mesh applications.  ``C = n_columns`` is the max column count
+    over every mesh in the grid; the kernel consumes the grid as its
+    1 x To x Ti :attr:`deep` schedule.  Hashable and purely static — a
+    jit/static and ``custom_vjp`` nondiff argument like
+    :class:`NetworkSchedule`.
     """
 
     tiles: tuple[tuple[tuple[MeshSchedule, MeshSchedule], ...], ...]
@@ -500,40 +519,12 @@ def tile_grid_schedule(n: int, to: int, ti: int,
     return TileGridSchedule(tiles=tuple(rows))
 
 
-@functools.lru_cache(maxsize=64)
-def _tile_grid_parity_np(grid: TileGridSchedule) -> tuple[np.ndarray,
-                                                          np.ndarray]:
-    c = grid.n_columns
-    pv = np.zeros((grid.to, grid.ti, c, 1), np.int32)
-    pu = np.zeros((grid.to, grid.ti, c, 1), np.int32)
-    for o, row in enumerate(grid.tiles):
-        for i, (sv, su) in enumerate(row):
-            pv[o, i, : sv.n_columns, 0] = sv.parity
-            pu[o, i, : su.n_columns, 0] = su.parity
-    return pv, pu
-
-
-def tile_grid_parity_arrays(grid: TileGridSchedule) -> tuple[Array, Array]:
-    """Stacked ``[To, Ti, C, 1]`` int32 parity inputs for the V/U meshes.
-
-    Identity-padded columns get parity 0 (their coefficient is the
-    identity cell, so the pairing is irrelevant).  Host-side build is
-    memoized per schedule (numpy, nothing trace-local cached), keyed by
-    content like the network variant — structurally equal grids share it.
-    """
-    pv, pu = _tile_grid_parity_np(grid)
-    return jnp.asarray(pv), jnp.asarray(pu)
-
-
-def pad_columns(coef: Array, n_columns: int) -> Array:
-    """Pad ``[..., C, 8, P]`` coefficients to ``n_columns`` with identity
-    cells (exact no-op columns in the sweep)."""
-    c = coef.shape[-3]
-    if c > n_columns:
-        raise ValueError(f"coefficients have {c} columns > pad {n_columns}")
-    if c == n_columns:
-        return coef
-    p = coef.shape[-1]
+def align_columns(coef: Array, source: np.ndarray) -> Array:
+    """Gather one tile's ``[C_t, 8, P]`` coefficients onto the aligned
+    kernel columns: column ``j`` takes the tile's column ``source[j]``,
+    or an identity cell (an exact no-op in the sweep) where it is -1."""
+    c, p = coef.shape[-3], coef.shape[-1]
     rows = jnp.asarray(_IDENTITY_ROWS, coef.dtype)[:, None]  # [8, 1]
-    ident = jnp.broadcast_to(rows, coef.shape[:-3] + (n_columns - c, 8, p))
-    return jnp.concatenate([coef, ident], axis=-3)
+    full = jnp.concatenate([coef, jnp.broadcast_to(rows, (1, 8, p))], axis=-3)
+    return jnp.take(full, jnp.asarray(np.where(source < 0, c, source)),
+                    axis=-3)

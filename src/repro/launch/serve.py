@@ -21,6 +21,7 @@ import numpy as np
 
 from repro import configs
 from repro.models import Model
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main(argv=None):
@@ -33,6 +34,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get_config(args.arch))

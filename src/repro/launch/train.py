@@ -28,6 +28,7 @@ from repro.data import TokenStream
 from repro.models import Model
 from repro.optim import AdamW, cosine_schedule
 from repro.runtime import FailureInjector, StragglerMonitor, plan_recovery
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.failures import Failure
 from repro.train import step as step_lib
 
@@ -60,6 +61,7 @@ def main(argv=None):
                     help="simulate a straggler host from this step")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get_config(args.arch))

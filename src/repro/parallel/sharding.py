@@ -29,15 +29,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# shard_map moved (and renamed its replication-check kwarg) across jax
-# releases; every shard_map user in the repo goes through this shim.
-if getattr(jax, "shard_map", None) is not None:  # jax >= 0.6 top-level API
-    shard_map_compat = functools.partial(jax.shard_map, check_vma=False)
-else:  # the experimental location (and arg name) of older releases
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    shard_map_compat = functools.partial(_shard_map_experimental,
-                                         check_rep=False)
+# every shard_map user in the repo goes through this one spelling: the
+# bodies hold Pallas calls, whose outputs carry no varying-axis types
+shard_map_compat = functools.partial(jax.shard_map, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,22 +151,22 @@ class TileGridShardSpecs:
     distributed layout shards the *work* over both mesh axes:
 
       * ``coef`` — the ``[L, To, Ti, C, 8, P]`` coefficient stacks (and
-        the ``[L, To, Ti, C, 1]`` parities / ``[L, To, Ti, 12, P]``
-        gains) of the deep-grid layout: REPLICATED.  Each device slices
+        the ``[L, C]`` parity tables / ``[L, To, Ti, 12, P]`` gains) of
+        the deep-grid layout: REPLICATED.  Each device slices
         its own tile-row slab (axis 1) in-body (``axis_index`` over the
         row axis).  They are small, and feeding them row-partitioned
         trips a GSPMD mis-partitioning bug on this jax version when the
         stacks are traced (built by concatenate under an enclosing jit,
         e.g. ``jit(grad(...))`` over unpacked tiles) — see the note in
         ``repro.kernels.ops``;
-      * ``x_plane`` — the ``[B, Ti, P]`` input planes: batch-split,
+      * ``x_plane`` — the ``[Ti, B, P]`` input planes: batch-split,
         replicated over tile rows (every row sweeps the whole input);
-      * ``o_plane`` — the ``[B, To, P]`` combined row outputs: split on
+      * ``o_plane`` — the ``[To, B, P]`` combined row outputs: split on
         both axes (each device owns its rows' outputs for its batch);
-      * ``stage`` — the ``[L, B, To, Ti, P]`` VJP stage residuals (the
-        stacked-sweep layout, batch-block axis second): batch and tile
-        rows both split, layer and input-tile axes whole;
-      * ``dx_plane`` — the ``[B, Ti, P]`` input cotangent *after* the
+      * ``stage`` — the ``[L, To, Ti, B, P]`` VJP stage residuals (the
+        kernel's tile-major layout): batch and tile rows both split,
+        layer and input-tile axes whole;
+      * ``dx_plane`` — the ``[Ti, B, P]`` input cotangent *after* the
         cross-device ``psum`` over the row axis (the matched-line
         combiner's transpose): batch-split, replicated over rows.
 
@@ -193,10 +187,10 @@ def tile_grid_shard_specs(row_axis: str = "rows",
     """The canonical (tile-row x batch) sharding of the tile-grid kernel."""
     return TileGridShardSpecs(
         coef=P(),
-        x_plane=P(data_axis),
-        o_plane=P(data_axis, row_axis),
-        stage=P(None, data_axis, row_axis),
-        dx_plane=P(data_axis),
+        x_plane=P(None, data_axis),
+        o_plane=P(row_axis, data_axis),
+        stage=P(None, row_axis, None, data_axis),
+        dx_plane=P(None, data_axis),
     )
 
 
@@ -222,12 +216,17 @@ def data_parallel(apply_fn, mesh: Mesh, *, axis_name: str = "data"):
                                   in_specs=(P(), P(axis_name)),
                                   out_specs=P(axis_name)))
 
+    replicated = NamedSharding(mesh, P())
+
     def call(params, x):
         b = x.shape[0]
         pad = (-b) % n_dev
-        if pad:
-            x = jnp.concatenate(
-                [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)], axis=0)
-        return fn(params, x)[:b]
+        if not pad:
+            return fn(params, x)
+        x = jnp.concatenate(
+            [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)], axis=0)
+        # a ragged slice of a batch-split array is undefined on a mesh
+        # with explicit axes (``jax.make_mesh``): gather, then slice
+        return jax.sharding.reshard(fn(params, x), replicated)[:b]
 
     return call

@@ -30,8 +30,11 @@ import numpy as np
 #:               and completed as failed;
 #:   rejected  — requests refused (or timed out) at admission because the
 #:               bounded queue was full;
-#:   recovered — mid-stream program swaps after a ``tile_down`` failure.
-COUNTERS = ("submitted", "served", "expired", "rejected", "recovered")
+#:   recovered — mid-stream program swaps after a ``tile_down`` failure;
+#:   failed    — queued or in-flight requests completed as failed because
+#:               a tick raised (the dispatch thread then stops).
+COUNTERS = ("submitted", "served", "expired", "rejected", "recovered",
+            "failed")
 
 
 class SLOTracker:

@@ -299,7 +299,10 @@ class ServingEngine:
     the caller's thread.  Async use: ``start()`` (or the context
     manager) spins up the dispatch thread; ``submit`` from any thread
     and ``req.wait()`` for the result future; ``stop()`` drains and
-    joins.
+    joins.  A tick that raises on the dispatch thread completes every
+    queued and in-flight request as failed, stops the thread, and
+    ``stop()`` (or leaving the context) re-raises the exception; later
+    ``submit`` calls raise too.
 
     Fault tolerance (analog path): with ``failure_injector=`` the engine
     polls the injector every tick; a fired ``tile_down`` swaps the
@@ -333,9 +336,11 @@ class ServingEngine:
             self._impl = _AnalogSlots(as_servable(program, params), slots,
                                       mesh=mesh, data_axis=data_axis)
         self._pending: deque[Request] = deque()
+        self._inflight: set[Request] = set()   # admitted, not completed
         self._cond = threading.Condition()
         self._thread: threading.Thread | None = None
         self._stop = threading.Event()
+        self._error: Exception | None = None   # dispatch-thread failure
 
     # -- admission ------------------------------------------------------
     def submit(self, req: Request, timeout: float | None = None) -> bool:
@@ -353,10 +358,15 @@ class ServingEngine:
                         return self._refuse(req)
                 else:
                     ok = self._cond.wait_for(
-                        lambda: len(self._pending) < self.max_queue,
+                        lambda: (self._error is not None
+                                 or len(self._pending) < self.max_queue),
                         timeout=timeout)
                     if not ok:
                         return self._refuse(req)
+            if self._error is not None:
+                req._finish(failed=True)
+                raise RuntimeError("serving dispatch thread failed") \
+                    from self._error
             req.submitted_tick = self.ticks
             req.submitted_at = time.perf_counter()
             self._pending.append(req)
@@ -427,6 +437,7 @@ class ServingEngine:
             while free > 0 and self._pending:
                 batch.append(self._pending.popleft())
                 free -= 1
+            self._inflight.update(batch)
             if batch:
                 self._cond.notify_all()   # queue shrank: wake blocked submits
         for req in batch:
@@ -436,6 +447,8 @@ class ServingEngine:
         t0 = time.perf_counter()
         completed = self._impl.step()
         self.slo.record_tick(time.perf_counter() - t0)
+        with self._cond:
+            self._inflight.difference_update(completed)
         for req in completed:
             req.completed_tick = self.ticks
             self.slo.count("served")
@@ -457,15 +470,32 @@ class ServingEngine:
             return bool(self._pending) or self._impl.n_active() > 0
 
     def _dispatch_loop(self) -> None:
-        while True:
-            if self._has_work():
-                self.tick()
-            elif self._stop.is_set():
-                return
-            else:
-                with self._cond:
-                    if not self._pending:
-                        self._cond.wait(timeout=0.02)
+        try:
+            while True:
+                if self._has_work():
+                    self.tick()
+                elif self._stop.is_set():
+                    return
+                else:
+                    with self._cond:
+                        if not self._pending:
+                            self._cond.wait(timeout=0.02)
+        except Exception as err:  # noqa: BLE001 - re-raised from stop()
+            self._fail_all(err)
+
+    def _fail_all(self, err: Exception) -> None:
+        """Record a dispatch failure and complete every queued and
+        in-flight request as failed, so no ``wait()`` blocks on a thread
+        that is gone."""
+        with self._cond:
+            self._error = err
+            doomed = [*self._pending, *self._inflight]
+            self._pending.clear()
+            self._inflight.clear()
+            self._cond.notify_all()
+        for req in doomed:
+            self.slo.count("failed")
+            req._finish(failed=True)
 
     def start(self) -> "ServingEngine":
         """Spin up the background dispatch thread."""
@@ -478,7 +508,8 @@ class ServingEngine:
         return self
 
     def stop(self, drain: bool = True) -> None:
-        """Stop the dispatch thread; by default after draining the queue."""
+        """Stop the dispatch thread; by default after draining the queue.
+        Re-raises the exception of a tick that failed on the thread."""
         if self._thread is None:
             return
         if not drain:
@@ -493,6 +524,8 @@ class ServingEngine:
             self._cond.notify_all()
         self._thread.join()
         self._thread = None
+        if self._error is not None:
+            raise self._error
 
     def __enter__(self) -> "ServingEngine":
         return self.start()

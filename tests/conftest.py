@@ -1,8 +1,11 @@
-"""Shared test configuration: CPU-only JAX, deterministic seeds, markers.
+"""Shared test configuration: CPU JAX, deterministic seeds, markers.
 
-The kernels run in Pallas interpret mode off-TPU (the ``ops`` wrappers
-default to it), so forcing the CPU platform here gives every test module
-the same interpret-mode defaults without per-file boilerplate.
+The tests run on the CPU, where the ``ops`` wrappers run the Pallas
+kernels in interpret mode; pinning the platform here gives every test
+module those defaults without per-file boilerplate (and keeps a host that
+ships libtpu from probing for a chip).  What the chip's compiler accepts
+is checked in ``tests/test_tpu_compile.py``; runs on the chip go through
+``chip_smoke.py``.
 """
 
 import os

@@ -202,6 +202,34 @@ def test_stop_without_drain_fails_pending(tiled_prog):
     assert served + eng.stats["rejected"] == 3
 
 
+def test_dispatch_failure_fails_requests_and_reraises_on_stop():
+    """A tick that raises on the dispatch thread must not strand callers:
+    queued and in-flight requests complete as failed, later submissions
+    raise, and stop() re-raises the tick's exception."""
+
+    class Broken:
+        n_in = n_out = 4
+
+        def apply(self, x):
+            raise FloatingPointError("device call failed")
+
+    eng = ServingEngine(Broken(), slots=2)
+    reqs = [Request(rid=i, features=np.ones(4, np.float32))
+            for i in range(5)]
+    for r in reqs:              # queued before the thread can fail
+        eng.submit(r)
+    eng.start()
+    assert all(r.wait(timeout=30) for r in reqs)
+    assert all(r.failed and r.result is None for r in reqs)
+    assert eng.stats["failed"] == 5 and eng.stats["served"] == 0
+    late = Request(rid=9, features=np.ones(4, np.float32))
+    with pytest.raises(RuntimeError, match="dispatch thread failed"):
+        eng.submit(late)
+    assert late.failed
+    with pytest.raises(FloatingPointError, match="device call failed"):
+        eng.stop()
+
+
 # ---------------------------------------------------------------------------
 # LM-vs-analog parity on the shared slot loop
 # ---------------------------------------------------------------------------
