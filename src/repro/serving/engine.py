@@ -17,7 +17,11 @@ ticks), in the shape of MaxText's ``offline_inference.py``:
     head-of-line blocking);
   * per-request **SLO accounting** (:class:`repro.runtime.slo.SLOTracker`):
     deadlines, served/expired/rejected/recovered counters, p50/p99 tick
-    latency, sustained QPS;
+    latency, and each tick cut into phases (``engine.expire``,
+    ``engine.admit``, ``engine.panel``, ``engine.put``, ``engine.launch``,
+    ``engine.fetch``, ``engine.complete``) whose seconds are summed and,
+    while the JAX profiler records, put on the device trace's clock;
+    every request is stamped when it is submitted, admitted and served;
   * the mid-stream **failure-recovery** hooks from the fault-tolerance
     work: a fired ``tile_down`` swaps in a recovered program between
     ticks and in-flight requests keep draining.
@@ -49,7 +53,7 @@ from typing import Any, Callable
 import jax.numpy as jnp
 import numpy as np
 
-from repro.runtime.slo import SLOTracker
+from repro.runtime.slo import SLOTracker, TickSpans
 from repro.serving.servable import ServableProgram, as_servable
 
 __all__ = ["Request", "ServingEngine"]
@@ -72,6 +76,15 @@ class Request:
     ``done`` is non-blocking.  On success ``result`` holds the output
     panel row (analog) or the generated token array (LM); on expiry or
     rejection ``failed`` is True and ``result`` stays None.
+
+    The engine stamps each request on the host clock
+    (``time.perf_counter``) and in ticks: ``submitted_at``/
+    ``submitted_tick`` when it is queued, ``admitted_at``/
+    ``admitted_tick`` when it takes a slot (one stamp for every request
+    of a tick), ``completed_at``/``completed_tick`` when it is served.
+    ``completed_at`` is set before ``_finish()``, so a subclass that
+    stamps its own completion there keeps its value.  The tick numbers
+    are the ``tick=`` of the engine's phase spans.
     """
 
     def __init__(self, rid: int, payload: Any = None, *,
@@ -94,7 +107,10 @@ class Request:
         self.failed = False
         self.submitted_tick = 0
         self.submitted_at: float | None = None
+        self.admitted_tick: int | None = None
+        self.admitted_at: float | None = None
         self.completed_tick: int | None = None
+        self.completed_at: float | None = None
         self._event = threading.Event()
 
     @property
@@ -172,7 +188,8 @@ class _AnalogSlots:
     def admit(self, req: Request) -> None:
         self.active.append(req)
 
-    def step(self) -> list[Request]:
+    def step(self, spans: TickSpans) -> list[Request]:
+        spans.phase("engine.panel")
         active, self.active = self.active, []
         try:
             d = int(self.servable.n_in)
@@ -181,7 +198,13 @@ class _AnalogSlots:
         panel = np.zeros((self.n_slots, d), np.float32)
         for i, req in enumerate(active):
             panel[i] = req.payload
-        out = np.asarray(self._apply(None, jnp.asarray(panel)))
+        spans.phase("engine.put")
+        x = jnp.asarray(panel)
+        spans.phase("engine.launch")
+        y = self._apply(None, x)
+        spans.phase("engine.fetch")
+        out = np.asarray(y)
+        spans.phase("engine.complete")
         for i, req in enumerate(active):
             req.result = out[i]
         return active
@@ -247,7 +270,8 @@ class _DecodeSlots:
             jnp.asarray(toks), self.cache, jnp.asarray(slot.pos, jnp.int32))
         slot.pos += 1
 
-    def step(self) -> list[Request]:
+    def step(self, spans: TickSpans) -> list[Request]:
+        spans.phase("engine.panel")
         active = [i for i, s in enumerate(self.slots) if s.req is not None]
         toks = np.zeros((self.n_slots,), np.int32)
         for i in active:
@@ -256,10 +280,15 @@ class _DecodeSlots:
         # positions: slots advance in lockstep from the shared max offset
         # (prefill above is slot-serial, so admitted slots start aligned)
         pos = max(self.slots[i].pos for i in active)
-        logits, self.cache = self._decode(
-            jnp.asarray(toks), self.cache, jnp.asarray(pos, jnp.int32))
-        arr = np.asarray(jnp.argmax(logits, -1)) if self.sample is None \
-            else np.asarray(self.sample(logits))
+        spans.phase("engine.put")
+        toks, pos = jnp.asarray(toks), jnp.asarray(pos, jnp.int32)
+        spans.phase("engine.launch")
+        logits, self.cache = self._decode(toks, self.cache, pos)
+        nxt = (jnp.argmax(logits, -1) if self.sample is None
+               else self.sample(logits))
+        spans.phase("engine.fetch")
+        arr = np.asarray(nxt)
+        spans.phase("engine.complete")
         completed = []
         for i in active:
             slot = self.slots[i]
@@ -336,6 +365,7 @@ class ServingEngine:
             self._impl = _AnalogSlots(as_servable(program, params), slots,
                                       mesh=mesh, data_axis=data_axis)
         self._pending: deque[Request] = deque()
+        self._deadlined: set[Request] = set()  # queued with a deadline
         self._inflight: set[Request] = set()   # admitted, not completed
         self._cond = threading.Condition()
         self._thread: threading.Thread | None = None
@@ -370,6 +400,8 @@ class ServingEngine:
             req.submitted_tick = self.ticks
             req.submitted_at = time.perf_counter()
             self._pending.append(req)
+            if req.deadline_ticks is not None:
+                self._deadlined.add(req)
             self.slo.count("submitted")
             self._cond.notify_all()
         return True
@@ -406,51 +438,74 @@ class ServingEngine:
     def _expire(self) -> None:
         """Complete overdue *queued* requests as failed, against the
         pre-increment tick counter (never silently stuck behind an
-        outage)."""
+        outage).  Only the queued requests that carry a deadline are
+        looked at, so a deep queue of requests without one costs
+        nothing here."""
         with self._cond:
+            late = {req for req in self._deadlined
+                    if self.ticks - req.submitted_tick >= req.deadline_ticks}
+            if not late:
+                return
+            self._deadlined -= late
             live: deque[Request] = deque()
             for req in self._pending:
-                if (req.deadline_ticks is not None
-                        and self.ticks - req.submitted_tick
-                        >= req.deadline_ticks):
+                if req in late:
                     self.slo.count("expired")
                     req._finish(failed=True)
                 else:
                     live.append(req)
-            if len(live) != len(self._pending):
-                self._pending = live
-                self._cond.notify_all()   # queue shrank: wake blocked submits
+            self._pending = live
+            self._cond.notify_all()   # queue shrank: wake blocked submits
 
     def tick(self) -> int:
         """One engine iteration; returns the number of requests completed.
 
         Ordering (load-bearing, see module docstring): poll failures and
         expire deadlines at the old tick number, advance the counter,
-        admit into free slots, then one fixed-shape device call.
+        admit into free slots, then one fixed-shape device call.  The
+        phases are recorded under the advanced tick number.
         """
+        spans = TickSpans(self.slo, self.ticks + 1)
+        try:
+            return self._tick(spans)
+        finally:
+            spans.end()
+
+    def _tick(self, spans: TickSpans) -> int:
+        spans.phase("engine.expire")
         self._check_failures()
         self._expire()
         self.ticks += 1
+        spans.phase("engine.admit")
         with self._cond:
             batch: list[Request] = []
             free = self._impl.free_slots()
             while free > 0 and self._pending:
                 batch.append(self._pending.popleft())
                 free -= 1
+            depth = len(self._pending)
+            if self._deadlined:
+                self._deadlined.difference_update(batch)
             self._inflight.update(batch)
             if batch:
                 self._cond.notify_all()   # queue shrank: wake blocked submits
+        now = time.perf_counter()
         for req in batch:
+            req.admitted_tick, req.admitted_at = self.ticks, now
             self._impl.admit(req)         # device work outside the lock
+        if spans.traced:
+            spans.note(n=len(batch), depth=depth,
+                       wait_s=sum(now - r.submitted_at for r in batch))
         if self._impl.n_active() == 0:
             return 0
         t0 = time.perf_counter()
-        completed = self._impl.step()
-        self.slo.record_tick(time.perf_counter() - t0)
+        completed = self._impl.step(spans)
+        now = time.perf_counter()
+        self.slo.record_tick(now - t0)
         with self._cond:
             self._inflight.difference_update(completed)
         for req in completed:
-            req.completed_tick = self.ticks
+            req.completed_tick, req.completed_at = self.ticks, now
             self.slo.count("served")
             req._finish()
         return len(completed)
@@ -491,6 +546,7 @@ class ServingEngine:
             self._error = err
             doomed = [*self._pending, *self._inflight]
             self._pending.clear()
+            self._deadlined.clear()
             self._inflight.clear()
             self._cond.notify_all()
         for req in doomed:
@@ -518,6 +574,7 @@ class ServingEngine:
                     self.slo.count("rejected")
                     req._finish(failed=True)
                 self._pending.clear()
+                self._deadlined.clear()
                 self._cond.notify_all()
         self._stop.set()
         with self._cond:
@@ -536,8 +593,9 @@ class ServingEngine:
     # -- accounting -----------------------------------------------------
     @property
     def stats(self) -> dict:
-        """SLO summary: counters, tick count, p50/p99 tick latency, qps,
-        plus the current queue depth."""
+        """SLO summary: counters, tick count, p50/p99 tick latency, the
+        summed seconds and count of each tick phase, plus the current
+        queue depth."""
         out = self.slo.summary()
         out["queue_depth"] = self.queue_depth
         return out

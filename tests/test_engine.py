@@ -9,6 +9,7 @@ import pytest
 
 import repro
 from repro import serving
+from repro.runtime.slo import PHASES
 from repro.serving import Request, ServableProgram, ServingEngine, as_servable
 
 jax.config.update("jax_platform_name", "cpu")
@@ -146,7 +147,11 @@ def test_stats_counters_and_latency_percentiles(tiled_prog):
     assert s["expired"] == 0 and s["rejected"] == 0 and s["recovered"] == 0
     assert s["ticks"] == 3 and s["queue_depth"] == 0
     assert s["p50_tick_us"] > 0 and s["p99_tick_us"] >= s["p50_tick_us"]
-    assert s["qps"] > 0
+    # each of the three device ticks ran every phase once; run() ends on
+    # a fourth tick that found nothing to admit
+    assert s["phase_n"] == dict(dict.fromkeys(PHASES, 3),
+                                **dict.fromkeys(PHASES[:2], 4))
+    assert all(s["phase_s"][p] > 0 for p in PHASES)
     # arrival/completion metadata stamped per request
     assert all(r.submitted_at is not None for r in reqs)
     assert [r.completed_tick for r in reqs] == [1, 1, 2, 2, 3]
@@ -158,7 +163,108 @@ def test_unknown_counter_rejected():
     t = SLOTracker()
     with pytest.raises(KeyError):
         t.count("nope")
-    assert t.percentile_us(50) is None and t.qps() is None
+    assert t.percentile_us(50) is None
+    assert t.summary()["phase_s"] == {} and t.summary()["phase_n"] == {}
+
+
+# ---------------------------------------------------------------------------
+# phase spans on the profiler's trace, and the request stamps
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def traced_run(tiled_prog, tmp_path_factory):
+    """Eleven requests through four slots on the dispatch thread, under
+    the profiler: the engine's spans read back from the trace."""
+    from jax.profiler import ProfileData
+
+    _, comp = tiled_prog
+    eng = ServingEngine(comp, slots=4)
+    reqs = _feature_reqs(11, seed=3)
+    for r in reqs[:4]:          # compile the panel shape before the trace
+        eng.submit(r)
+    eng.run()
+    out = tmp_path_factory.mktemp("trace")
+    jax.profiler.start_trace(str(out))
+    try:
+        with eng:
+            for r in reqs[4:]:
+                eng.submit(r)
+            assert all(r.wait(timeout=60) for r in reqs)
+    finally:
+        jax.profiler.stop_trace()
+    data = ProfileData.from_file(str(next(out.rglob("*.xplane.pb"))))
+    spans = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name, k,
+              dict(ev.stats))
+             for plane in data.planes for k, line in enumerate(plane.lines)
+             for ev in line.events if ev.name.startswith("engine.")]
+    return eng, reqs[4:], sorted(spans)
+
+
+def test_tick_spans_partition_each_tick_on_one_thread(traced_run):
+    _, reqs, spans = traced_run
+    assert spans
+    assert len({s[3] for s in spans}) == 1          # one host thread
+    by_tick: dict = {}
+    for s in spans:
+        by_tick.setdefault(s[4]["tick"], []).append(s)
+    served = {r.completed_tick for r in reqs}
+    assert served <= set(by_tick)
+    for tick, group in by_tick.items():
+        names = [s[2] for s in group]
+        # a tick either ran the device step or found nothing to admit
+        assert names in (list(PHASES), list(PHASES[:2])), (tick, names)
+        for a, b in zip(group, group[1:]):
+            assert a[0] <= a[1] <= b[0]             # in order, no overlap
+    ticks = sorted(by_tick)
+    assert ticks == list(range(ticks[0], ticks[-1] + 1))
+
+
+def test_admit_spans_match_the_request_stamps(traced_run):
+    _, reqs, spans = traced_run
+    admits = {s[4]["tick"]: s[4] for s in spans if s[2] == "engine.admit"}
+    for tick, meta in admits.items():
+        mine = [r for r in reqs if r.admitted_tick == tick]
+        assert meta["n"] == len(mine)
+        assert meta["wait_s"] == pytest.approx(
+            sum(r.admitted_at - r.submitted_at for r in mine), abs=1e-9)
+        assert meta["depth"] >= 0
+    assert sum(m["n"] for m in admits.values()) == len(reqs)
+    for r in reqs:
+        assert r.submitted_at <= r.admitted_at <= r.completed_at
+        assert r.submitted_tick <= r.admitted_tick <= r.completed_tick
+
+
+def test_no_annotation_is_built_with_the_profiler_off(tiled_prog,
+                                                      monkeypatch):
+    class Refused(jax.profiler.TraceAnnotation):
+        def __init__(self, *args, **kwargs):
+            raise AssertionError("annotation built with the profiler off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+    _, comp = tiled_prog
+    eng = ServingEngine(comp, slots=2)
+    for r in _feature_reqs(5):
+        eng.submit(r)
+    eng.run()
+    assert eng.stats["served"] == 5
+    assert eng.stats["phase_n"]["engine.fetch"] == 3
+
+
+def test_a_subclass_completion_stamp_wins(tiled_prog):
+    class Stamped(Request):
+        def _finish(self, failed=False):
+            self.completed_at = -1.0
+            super()._finish(failed)
+
+    _, comp = tiled_prog
+    eng = ServingEngine(comp, slots=2)
+    plain = _feature_reqs(1)[0]
+    own = Stamped(rid=1, features=np.ones(8, np.float32))
+    eng.submit(plain)
+    eng.submit(own)
+    eng.run()
+    assert own.completed_at == -1.0
+    assert plain.completed_at >= plain.admitted_at >= plain.submitted_at
 
 
 # ---------------------------------------------------------------------------
@@ -200,6 +306,38 @@ def test_stop_without_drain_fails_pending(tiled_prog):
     assert all(r.done for r in reqs)
     served = sum(1 for r in reqs if not r.failed)
     assert served + eng.stats["rejected"] == 3
+
+
+def test_expiry_fails_only_late_deadlined_requests_in_queue_order(
+        tiled_prog):
+    """A queue that mixes requests with and without a deadline: only the
+    late deadlined ones expire, the rest serve in submission order, and
+    no admitted or expired request is left among those still watched."""
+    _, comp = tiled_prog
+    eng = ServingEngine(comp, slots=2)
+    reqs = [Request(rid=i, features=np.ones(8, np.float32),
+                    deadline_ticks=2 if i % 3 == 1 else None)
+            for i in range(12)]
+    for r in reqs:
+        eng.submit(r)
+    order = []
+    real_finish = Request._finish
+
+    def finish(self, failed=False):
+        order.append((self.rid, failed))
+        real_finish(self, failed)
+
+    Request._finish = finish
+    try:
+        eng.run()
+    finally:
+        Request._finish = real_finish
+    expired = [rid for rid, failed in order if failed]
+    served = [rid for rid, failed in order if not failed]
+    assert expired == [4, 7, 10]
+    assert served == [0, 1, 2, 3, 5, 6, 8, 9, 11]
+    assert eng.stats["expired"] == 3 and eng.stats["served"] == 9
+    assert not eng._deadlined
 
 
 def test_dispatch_failure_fails_requests_and_reraises_on_stop():
