@@ -310,7 +310,7 @@ def sharded(seed: int, batch: int = 256, n_rows: int = 2,
     # each device computed its own (row slab x batch shard) of every layer
     # and holds a distinct block of the output
     shards = {(s.device.id, str(s.index)) for s in y_n.addressable_shards}
-    local = f"f32[{deep.to // n_rows},{batch // n_data},{deep.n // 2}]"
+    local = f"f32[{deep.to // n_rows},{deep.n // 2},{batch // n_data}]"
     row_slabs = local in _custom_call_shapes(fwd_n)
 
     seq = AnalogSequence(n=8, depth=2, backend="pallas")

@@ -24,7 +24,7 @@ The pipeline mirrors the paper's digital->analog transfer (Fig. 11):
   — the same ``imperfect_cell_matrix`` + key consumption as the reference
   path, so calibration and serving see the device draw-for-draw.
 * :func:`lower` — emit the megakernel inputs (an L x 1 x 1
-  ``DeepGridSchedule`` + stacked ``[L, 1, 1, C, 8, P]`` coefficients)
+  ``DeepGridSchedule`` + stacked ``[L, 1, 1, C, P, 8]`` coefficients)
   through the existing ``ops.pack_network`` leaf-identity cache and
   return a :class:`CompiledProgram` whose ``apply`` is pure kernel
   execution.  :func:`lower_deep` lowers a *chain* of tiled programs onto
@@ -392,7 +392,7 @@ def lower(prog: AnalogProgram, *, block_b: int | None = None,
     Builds the per-layer kernel argument dicts (device-snapped phases,
     attenuation, digital gamma, bound noise keys), then emits the
     L x 1 x 1 :class:`DeepGridSchedule` and the stacked
-    ``[L, 1, 1, C, 8, P]`` coefficient tensors through ``ops.pack_network`` — the same leaf-identity pack
+    ``[L, 1, 1, C, P, 8]`` coefficient tensors through ``ops.pack_network`` — the same leaf-identity pack
     cache the serving path reads, so the tensors are packed exactly once,
     here, and every subsequent ``apply`` (and every serving tick) finds
     them already resident.

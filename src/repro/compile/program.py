@@ -15,7 +15,7 @@ serving kernels.  This module holds the IR those passes transform:
   single matrix).
 * :class:`CompiledProgram` — the ``lower`` pass output: a static
   L x 1 x 1 :class:`~repro.kernels.schedule.DeepGridSchedule` plus the
-  stacked ``[L, 1, 1, C, 8, P]`` megakernel coefficients, pre-emitted
+  stacked ``[L, 1, 1, C, P, 8]`` megakernel coefficients, pre-emitted
   through the pack cache so ``apply`` is pure kernel execution with zero
   packing work.
 * :class:`TiledAnalogProgram` — a (To x Ti) grid of per-tile-SVD
@@ -25,11 +25,11 @@ serving kernels.  This module holds the IR those passes transform:
   single-layer pipeline over every tile independently.
 * :class:`CompiledTiledProgram` — the ``lower_tiled`` output: a static
   1 x To x Ti :class:`~repro.kernels.schedule.DeepGridSchedule` plus the
-  stacked ``[1, To, Ti, C, 8, P]`` tile-grid tensors; ``apply`` is one
+  stacked ``[1, To, Ti, C, P, 8]`` tile-grid tensors; ``apply`` is one
   tile-grid megakernel call (all To*Ti meshes swept and row-combined in
   VMEM).
 * :class:`CompiledDeepProgram` — the ``lower_deep`` output: an L-layer
-  *cascade* of tile grids on one ``[L, To, Ti, C, 8, P]`` deep
+  *cascade* of tile grids on one ``[L, To, Ti, C, P, 8]`` deep
   megakernel — ``apply`` is a single launch for the whole network,
   inter-layer detection in VMEM, placements folded into the launch.
 
@@ -366,7 +366,7 @@ class CompiledTiledProgram:
     tile_args: tuple             # [To][Ti] of kernel argument dicts
     hardware: hw_lib.HardwareModel | None
     grid: "object"               # 1 x To x Ti DeepGridSchedule (static)
-    packed: tuple                # (coef_v [To,Ti,8*,P], coef_u, gains)
+    packed: tuple                # (coef_v [1,To,Ti,C,P,8], coef_u, gains)
     block_b: int | None = None
     interpret: bool | None = None
     # yield-aware placement (compile/placement.py): the kernel runs the

@@ -9,7 +9,9 @@ through all S = N(N-1)/2 cells without intermediate storage (HBM traffic is
 2 reads + 2 writes of the panel total, instead of per-column round trips).
 
 Layout choices (see DESIGN.md):
-  * planes [B, P] with P = N/2 on the lane dimension (128-aligned for N>=256);
+  * planes [B, P] with P = N/2 on the lane dimension (128-aligned for N>=256)
+    in the single-mesh and fused-layer kernels; the deep-grid kernels turn
+    them to [P, B], the batch on lanes (see the deep-grid notes below);
   * coefficients [C, 8, P]: 8 rows = (t00, t01, t10, t11) x (re, im) per pair
     slot, broadcast over the batch sublanes.  The 2x2 cells are **arbitrary
     complex matrices** — ideal unitary rotations and the hardware model's
@@ -217,23 +219,25 @@ def rfnn_linear_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # Backward pass building blocks (the custom VJPs)
 # ---------------------------------------------------------------------------
 
-def adjoint_coefficients(coef: jax.Array) -> jax.Array:
+def adjoint_coefficients(coef: jax.Array, axis: int = -2) -> jax.Array:
     """Conjugate-transpose each packed 2x2 cell, column layout preserved.
 
     Rows (t00, t01, t10, t11) x (re, im) -> (t00*, t10*, t01*, t11*).  The
     adjoint propagates the cotangent in the reversed sweep: the transpose
     of the real-representation Jacobian of ``y = T x`` is ``T^H`` for any
     complex ``T``.  For unitary columns it is also the exact inverse, which
-    is the PR-1 state-recompute trick as a special case.  Rows live on axis
-    -2, so both per-mesh ``[C, 8, P]`` and stacked network ``[L, C, 8, P]``
-    layouts transform in place.
+    is the PR-1 state-recompute trick as a special case.  The 8 rows live
+    on ``axis``: -2 for the per-mesh ``[C, 8, P]`` and stacked network
+    ``[L, C, 8, P]`` layouts, -1 for the deep grid's ``[.., C, P, 8]``.
     """
     idx = jnp.asarray([0, 1, 4, 5, 2, 3, 6, 7])
     sign = jnp.asarray([1.0, -1.0] * 4, coef.dtype)
-    return jnp.take(coef, idx, axis=-2) * sign[:, None]
+    sign = sign.reshape((8,) + (1,) * (-1 - axis))
+    return jnp.take(coef, idx, axis=axis) * sign
 
 
-def inverse_coefficients(coef: jax.Array, eps: float = 1e-12) -> jax.Array:
+def inverse_coefficients(coef: jax.Array, eps: float = 1e-12,
+                         axis: int = -2) -> jax.Array:
     """Analytic per-cell 2x2 inverse in the packed coefficient layout.
 
     ``inv(t) = adj(t) / det(t)`` with ``det = t00 t11 - t01 t10``.  This is
@@ -242,13 +246,14 @@ def inverse_coefficients(coef: jax.Array, eps: float = 1e-12) -> jax.Array:
     no per-column residuals: ``s_c = T_c^{-1} s_{c+1}``.  Hardware cells
     are well-conditioned (|det| ~ cell_gain^2); ``eps`` guards the
     identity-padded slots' neighbourhood against exact zeros.  Like
-    :func:`adjoint_coefficients`, rows live on axis -2 (works on ``[C, 8,
-    P]`` and ``[L, C, 8, P]`` alike).
+    :func:`adjoint_coefficients`, the rows live on ``axis``.
     """
-    t00 = coef[..., 0, :] + 1j * coef[..., 1, :]
-    t01 = coef[..., 2, :] + 1j * coef[..., 3, :]
-    t10 = coef[..., 4, :] + 1j * coef[..., 5, :]
-    t11 = coef[..., 6, :] + 1j * coef[..., 7, :]
+    row = [jax.lax.index_in_dim(coef, k, axis % coef.ndim, keepdims=False)
+           for k in range(8)]
+    t00 = row[0] + 1j * row[1]
+    t01 = row[2] + 1j * row[3]
+    t10 = row[4] + 1j * row[5]
+    t11 = row[6] + 1j * row[7]
     det = t00 * t11 - t01 * t10
     inv_det = jnp.conj(det) / jnp.maximum(jnp.abs(det) ** 2, eps)
     i00, i01 = t11 * inv_det, -t01 * inv_det
@@ -256,7 +261,7 @@ def inverse_coefficients(coef: jax.Array, eps: float = 1e-12) -> jax.Array:
     out = jnp.stack(
         [jnp.real(i00), jnp.imag(i00), jnp.real(i01), jnp.imag(i01),
          jnp.real(i10), jnp.imag(i10), jnp.real(i11), jnp.imag(i11)],
-        axis=-2,
+        axis=axis,
     )
     return out.astype(coef.dtype)
 
@@ -525,9 +530,9 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # tile row are summed in VMEM (matched-line power combiner) and the
 # combined row magnitudes are re-detected *inside the kernel* to feed the
 # next layer as a real signal (zero imaginary planes) — inter-layer
-# activations never touch HBM.  Gains are [L, To, Ti, 12, P]: rows 0-3
+# activations never touch HBM.  Gains are [L, To, Ti, P, 12]: rows 0-3
 # g0, 4-7 g1, 8-11 g2, each as (even re, even im, odd re, odd im).
-# Coefficients stack to [L, To, Ti, C, 8, P] over the aligned column
+# Coefficients stack to [L, To, Ti, C, P, 8] over the aligned column
 # schedule of ``repro.kernels.schedule.DeepGridSchedule``: every column
 # has ONE parity across a layer's tiles (an SMEM ``[L, C]`` table per
 # mesh), tiles whose own schedules disagree sit the column out as an
@@ -535,14 +540,20 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # re-detection needs every row of a layer, so one grid step carries one
 # batch block through the entire network.
 #
-# Layout: planes are [To, Ti, B, P] — tile axes leading, the batch block
-# on sublanes, the P = n/2 pair slots on lanes.  Every tile-structure
-# step is then a leading-axis operation the chip does per vreg with no
-# relayout: broadcasting an input over the To rows, the row combine (a
-# sum over Ti), its transpose (a sum over To), and handing a layer's To
-# rows to the next layer's Ti input tiles.  Coefficient and gain rows
-# load as [To, Ti, 1, P] slices and broadcast over the batch sublanes;
-# coefficient gradients reduce over the sublanes back to that shape.
+# Layout: planes are [To, Ti, P, B] — tile axes leading, the P = n/2 pair
+# slots on sublanes and the batch block on lanes (the single-mesh and
+# fused-layer kernels above keep [B, P], with the batch on sublanes).  A
+# 16-channel tile's P = 8 fills one sublane tile, so every vreg of a
+# plane holds 128 batch rows instead of 8 pair slots.  Every
+# tile-structure step stays a leading-axis operation the chip does per
+# vreg with no relayout: broadcasting an input over the To rows, the row
+# combine (a sum over Ti), its transpose (a sum over To), and handing a
+# layer's To rows to the next layer's Ti input tiles.  The odd columns'
+# pair shift moves along the sublanes.  Coefficient and gain rows load as
+# [To, Ti, P, 1] columns (the row index is the last, lane axis of the
+# packed tensors) and broadcast along the batch lanes, once per column
+# per grid step; coefficient gradients reduce over the lanes back to that
+# shape.
 #
 # The grid's To*Ti tiles are NOT unrolled: a layer's tiles are
 # independent sweeps over the same aligned column count, so the body
@@ -565,7 +576,7 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # Residuals follow the single-layer kernel's rule: everything inside a
 # mesh is recomputed by the reversed inverse/adjoint sweep (no per-column
 # state), but |z| is not invertible, so each tile saves its two pre-gain
-# stage boundaries (post-V, post-U) — 8 stacked [L, To, Ti, B, P] planes
+# stage boundaries (post-V, post-U) — 8 stacked [L, To, Ti, P, B] planes
 # total, identical to what the per-layer / per-tile composition would
 # have stored, minus all the inter-layer HBM round trips and per-layer
 # kernel launches.  The layer-boundary activations themselves are NOT
@@ -577,10 +588,13 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 #
 # VMEM: coefficient, gain and gradient-accumulator tensors are resident
 # for the whole call (one buffer each); batch-blocked planes are double
-# buffered.  On the chip every buffer's last two axes are tiled (8, 128),
-# so a P-lane plane or an (8, P) coefficient slab occupies 128 lanes;
-# :func:`vmem_bytes` counts that, and a call whose count exceeds the
-# default scoped limit raises ``vmem_limit_bytes`` to fit.
+# buffered.  On the chip every buffer's last two axes are tiled (8, 128):
+# a plane row costs P sublanes (rounded up to 8) x 4 B, and a (P, 8)
+# coefficient slab occupies a whole (8, 128) tile.  :func:`vmem_bytes`
+# counts that, ``ops._vmem_auto_block`` sizes the batch block from it (a
+# multiple of 128 lanes, or the whole padded batch when that fits), and a
+# call whose count exceeds the default scoped limit raises
+# ``vmem_limit_bytes`` to fit.
 
 #: Mosaic's default scoped-VMEM limit on v5e.
 _SCOPED_VMEM_DEFAULT = 16 * 2**20
@@ -609,19 +623,19 @@ def _deep_compiler_params(resident, blocked):
 
 
 def _vshift_down(x):
-    """x[..., p] <- x[..., p+1] (zero into the last lane)."""
-    return jnp.concatenate([x[..., 1:], jnp.zeros_like(x[..., :1])],
-                           axis=-1)
+    """x[..., p, :] <- x[..., p+1, :] (zero into the last sublane row)."""
+    return jnp.concatenate([x[..., 1:, :], jnp.zeros_like(x[..., :1, :])],
+                           axis=-2)
 
 
 def _vshift_up(x, first):
-    """x[..., p] <- x[..., p-1], lane 0 taken from ``first``."""
-    return jnp.concatenate([first[..., :1], x[..., :-1]], axis=-1)
+    """x[..., p, :] <- x[..., p-1, :], row 0 taken from ``first``."""
+    return jnp.concatenate([first[..., :1, :], x[..., :-1, :]], axis=-2)
 
 
 def _vlast(x, last):
-    """x with its last lane replaced from ``last``."""
-    return jnp.concatenate([x[..., :-1], last[..., -1:]], axis=-1)
+    """x with its last pair-slot row replaced from ``last``."""
+    return jnp.concatenate([x[..., :-1, :], last[..., -1:, :]], axis=-2)
 
 
 def _vcolumn_even(cc, state):
@@ -631,7 +645,7 @@ def _vcolumn_even(cc, state):
 
 
 def _vcolumn_odd(cc, state):
-    """Odd column: rotate (o_p, e_{p+1}); the two wrap lanes pass
+    """Odd column: rotate (o_p, e_{p+1}); the two wrap rows pass
     through (odd columns hold no cell in the wrap-around pair)."""
     er, ei, orr, oi = state
     a2r, a2i, b2r, b2i = _rotate(cc, orr, oi,
@@ -641,15 +655,15 @@ def _vcolumn_odd(cc, state):
 
 
 def _coef_rows(coef_ref, l, c):
-    """Column ``c`` of layer ``l``: its 8 coefficient rows, [To, Ti, 1, P]
-    each (broadcast over the batch sublanes)."""
-    return [coef_ref[l, :, :, c, k:k + 1, :] for k in range(8)]
+    """Column ``c`` of layer ``l``: its 8 coefficient rows, [To, Ti, P, 1]
+    each (broadcast along the batch lanes)."""
+    return [coef_ref[l, :, :, c, :, k:k + 1] for k in range(8)]
 
 
 def _vrun_columns(coef_ref, par_ref, l, state):
     """Stacked-tile column sweep of layer ``l``'s mesh: ``coef_ref``
-    [L, To, Ti, C, 8, P], ``par_ref`` its SMEM [L, C] parity table, state
-    planes [To, Ti, B, P] (batch-materialized — fori_loop carries must be
+    [L, To, Ti, C, P, 8], ``par_ref`` its SMEM [L, C] parity table, state
+    planes [To, Ti, P, B] (batch-materialized — fori_loop carries must be
     full-shape)."""
     def body(c, s):
         cc = _coef_rows(coef_ref, l, c)
@@ -661,14 +675,14 @@ def _vrun_columns(coef_ref, par_ref, l, state):
 
 
 def _vconj_dot(xr, xi, gr, gi):
-    """Batch-summed conj(x) * g over stacked planes -> [To, Ti, 1, P]
-    pair."""
-    return (jnp.sum(xr * gr + xi * gi, axis=2, keepdims=True),
-            jnp.sum(xr * gi - xi * gr, axis=2, keepdims=True))
+    """Batch-summed conj(x) * g over stacked planes -> [To, Ti, P, 1]
+    pair (a lane reduction)."""
+    return (jnp.sum(xr * gr + xi * gi, axis=-1, keepdims=True),
+            jnp.sum(xr * gi - xi * gr, axis=-1, keepdims=True))
 
 
 def _vrows_from_pairs(a, ga, b, gb):
-    """The 8 per-coefficient conj-dot gradient rows, [To, Ti, 1, P]
+    """The 8 per-coefficient conj-dot gradient rows, [To, Ti, P, 1]
     each."""
     return (_vconj_dot(a[0], a[1], ga[0], ga[1])
             + _vconj_dot(b[0], b[1], ga[0], ga[1])
@@ -683,7 +697,7 @@ def _vcoef_grad_even(s_in, g_out):
 
 
 def _vcoef_grad_odd(s_in, g_out):
-    """Odd pairing: (a, b) = (o_p, e_{p+1}); the wrap lane holds no cell
+    """Odd pairing: (a, b) = (o_p, e_{p+1}); the wrap row holds no cell
     so its gradient rows are zeroed."""
     er, ei, orr, oi = s_in
     ger, gei, gor, goi = g_out
@@ -691,16 +705,17 @@ def _vcoef_grad_odd(s_in, g_out):
         (orr, oi), (gor, goi),
         (_vshift_down(er), _vshift_down(ei)),
         (_vshift_down(ger), _vshift_down(gei)))
-    lane = jax.lax.broadcasted_iota(jnp.int32, rows[0].shape, 3)
-    wrap = lane == rows[0].shape[3] - 1
+    slot = jax.lax.broadcasted_iota(jnp.int32, rows[0].shape, 2)
+    wrap = slot == rows[0].shape[2] - 1
     return tuple(jnp.where(wrap, 0.0, r) for r in rows)
 
 
 def _vrun_columns_bwd(ci_ref, ca_ref, par_ref, dco_ref, l, state, cot):
     """Reversed stacked-tile sweep of layer ``l``'s mesh: recompute
     states via the per-cell inverse slab, accumulate each column's
-    coefficient gradient into its slot of the revisited ``dco_ref``,
-    propagate the cotangent via the adjoint slab."""
+    coefficient gradient (its 8 lane-reduced rows joined along the lanes
+    into one [To, Ti, P, 8] slab) into its slot of the revisited
+    ``dco_ref``, propagate the cotangent via the adjoint slab."""
     n_cols = ci_ref.shape[3]
 
     def branch(step, coef_grad, ci, ca):
@@ -718,8 +733,7 @@ def _vrun_columns_bwd(ci_ref, ca_ref, par_ref, dco_ref, l, state, cot):
                            branch(_vcolumn_odd, _vcoef_grad_odd, ci, ca),
                            branch(_vcolumn_even, _vcoef_grad_even, ci, ca),
                            carry)
-        for r, row in enumerate(out[4:12]):
-            dco_ref[l, :, :, c, r:r + 1, :] += row
+        dco_ref[l, :, :, c] += jnp.concatenate(out[4:12], axis=-1)
         return (*out[0:4], *out[12:16])
 
     out = jax.lax.fori_loop(0, n_cols, body, (*state, *cot))
@@ -727,8 +741,8 @@ def _vrun_columns_bwd(ci_ref, ca_ref, par_ref, dco_ref, l, state, cot):
 
 
 def _layer_gain_rows(gains_ref, l):
-    """Layer ``l``'s 12 gain rows, [To, Ti, 1, P] each."""
-    return [gains_ref[l, :, :, k:k + 1, :] for k in range(12)]
+    """Layer ``l``'s 12 gain rows, [To, Ti, P, 1] each."""
+    return [gains_ref[l, :, :, :, k:k + 1] for k in range(12)]
 
 
 def _net_layer_stages(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref, l, g,
@@ -736,7 +750,7 @@ def _net_layer_stages(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref, l, g,
     """g0 -> V -> g1 -> U for stacked layer ``l``; returns (v, u) states.
 
     ``g`` is the layer's 12 gain rows, ``state`` the stacked
-    [To, Ti, B, P] input planes."""
+    [To, Ti, P, B] input planes."""
     er, ei = _cmul(state[0], state[1], g[0], g[1])
     orr, oi = _cmul(state[2], state[3], g[2], g[3])
     v = _vrun_columns(coef_v_ref, par_v_ref, l, (er, ei, orr, oi))
@@ -779,13 +793,13 @@ def _layer_linear_bwd(cv_inv_ref, cv_adj_ref, par_v_ref, cu_inv_ref,
     """Unwind the linear stages g2 -> U -> g1 -> V -> g0 of stacked layer
     ``l`` — every (To, Ti) tile at once.
 
-    ``gz`` is the cotangent of the post-g2 complex state as [To, 1, B, P]
+    ``gz`` is the cotangent of the post-g2 complex state as [To, 1, P, B]
     row planes broadcast to every tile (the row combine is a sum, so each
     tile of a row sees its row's cotangent).  ``x_in``/``v``/``u`` are
     the stacked layer input and stage states.  Coefficient and gain
     gradients accumulate into layer ``l``'s slots of the revisited
     ``dcv_ref``/``dcu_ref``/``dg_ref``; returns the per-tile input
-    cotangent planes [To, Ti, B, P] (NOT yet summed over rows — the
+    cotangent planes [To, Ti, P, B] (NOT yet summed over rows — the
     caller applies the combine's transpose).
     """
     gzer, gzei, gzor, gzoi = gz
@@ -811,31 +825,30 @@ def _layer_linear_bwd(cv_inv_ref, cv_adj_ref, par_v_ref, cu_inv_ref,
     gxer, gxei = _cmul(g[0], -g[1], gs0[0], gs0[1])
     gxor, gxoi = _cmul(g[2], -g[3], gs0[2], gs0[3])
 
-    for k, row in enumerate(dg0 + dg1 + dg2):
-        dg_ref[l, :, :, k:k + 1, :] += row
+    dg_ref[l] += jnp.concatenate(dg0 + dg1 + dg2, axis=-1)
     return gxer, gxei, gxor, gxoi
 
 
 def _broadcast_tiles(planes, to):
-    """[Ti, B, P] input planes -> stacked [To, Ti, B, P] (every tile row
+    """[Ti, P, B] input planes -> stacked [To, Ti, P, B] (every tile row
     sweeps the whole input), batch-materialized for the fori carries."""
     return tuple(jnp.broadcast_to(t[None], (to,) + t.shape) for t in planes)
 
 
 def _row_combine(z):
     """Matched-line row combine: each row sums its Ti tile outputs,
-    [To, Ti, B, P] -> [To, B, P]."""
+    [To, Ti, P, B] -> [To, P, B]."""
     return tuple(t.sum(axis=1) for t in z)
 
 
 def _deep_forward(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref, gains_ref,
                   x_refs, stage_refs=None):
     """All L layers of the (To x Ti) grid on one batch block, every
-    layer's To*Ti tiles swept together as stacked [To, Ti, B, P] planes.
+    layer's To*Ti tiles swept together as stacked [To, Ti, P, B] planes.
 
-    Input planes are [Ti, B, P]; returns the *last* layer's combined
-    post-g2 row planes ([To, B, P] x 4 — the caller applies the
-    readout).  With ``stage_refs`` (the 8 ``[L, To, Ti, B, P]`` residual
+    Input planes are [Ti, P, B]; returns the *last* layer's combined
+    post-g2 row planes ([To, P, B] x 4 — the caller applies the
+    readout).  With ``stage_refs`` (the 8 ``[L, To, Ti, P, B]`` residual
     refs of the VJP forward) every tile's two pre-gain stage boundaries
     are saved as whole slabs; inference passes ``None``.
     """
@@ -886,7 +899,7 @@ def deepgrid_fwd_kernel(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref,
                         gains_ref, xer_ref, xei_ref, xor_ref, xoi_ref,
                         *out_refs, detect_last: bool):
     """VJP forward: identical sweep, plus every tile's two pre-gain stage
-    boundaries (post-V, post-U) into [L, To, Ti, B, P] residual planes."""
+    boundaries (post-V, post-U) into [L, To, Ti, P, B] residual planes."""
     n_out = 2 if detect_last else 4
     z = _deep_forward(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref,
                       gains_ref, (xer_ref, xei_ref, xor_ref, xoi_ref),
@@ -911,7 +924,7 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
     and crossing a layer boundary re-detects the previous layer's rows
     from their saved post-U states and converts the (real) cotangent
     through the |detect| backward.  Layer 0 writes the input cotangent
-    planes [Ti, B, P].
+    planes [Ti, P, B].
     """
     n_cot = 2 if detect_last else 4
     cot_refs = cot_and_out_refs[:n_cot]
@@ -933,7 +946,7 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
         return (suer_ref[l], suei_ref[l], suor_ref[l], suoi_ref[l])
 
     def row_z(l):
-        """Recompute layer l's combined post-g2 row planes [To, B, P]
+        """Recompute layer l's combined post-g2 row planes [To, P, B]
         from the saved post-U stages (elementwise — no sweep)."""
         return _row_combine(_tile_z(saved_u(l), _layer_gain_rows(gains_ref,
                                                                  l)))
@@ -942,7 +955,7 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
         goe_ref, goo_ref = cot_refs
         gz = _detect_bwd_z(row_z(n_layers - 1), goe_ref[...], goo_ref[...])
     else:
-        gz = tuple(r[...] for r in cot_refs)              # [To, B, P]
+        gz = tuple(r[...] for r in cot_refs)              # [To, P, B]
 
     for l in range(n_layers - 1, -1, -1):
         if l == 0:
@@ -958,7 +971,7 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
             zero = jnp.zeros_like(be)
             state_in = _broadcast_tiles((be, zero, bo, zero), to)
         # the row combine is a sum: every tile of a row sees the row's
-        # cotangent ([To, 1, B, P] broadcast across the stacked sweep)
+        # cotangent ([To, 1, P, B] broadcast across the stacked sweep)
         gx = _layer_linear_bwd(
             cv_inv_ref, cv_adj_ref, par_v_ref, cu_inv_ref, cu_adj_ref,
             par_u_ref, dcv_ref, dcu_ref, dg_ref, l,
@@ -966,7 +979,7 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
             saved_u(l), tuple(t[:, None] for t in gz))
         # combine's transpose: each input tile sums its cotangent over
         # the To rows
-        dx = tuple(t.sum(axis=0) for t in gx)             # [Ti, B, P]
+        dx = tuple(t.sum(axis=0) for t in gx)             # [Ti, P, B]
         if l > 0:
             # boundary crossing keeps only the real cotangent planes (the
             # imaginary planes of an inter-layer input are structurally
@@ -1001,19 +1014,19 @@ def _deep_coef_bytes(n_layers: int, to: int, ti: int, n_cols: int,
 
 def _deep_shapes(n, n_layers, to, ti, n_cols, batch_block):
     """Shapes of the deep call's operand classes: coefficient stack, gain
-    stack, and the per-block x / output / stage planes."""
+    stack, and the per-block x / output / stage planes (batch last)."""
     p = n // 2
-    return ((n_layers, to, ti, n_cols, 8, p), (n_layers, to, ti, 12, p),
-            (ti, batch_block, p), (to, batch_block, p),
-            (n_layers, to, ti, batch_block, p))
+    return ((n_layers, to, ti, n_cols, p, 8), (n_layers, to, ti, p, 12),
+            (ti, p, batch_block), (to, p, batch_block),
+            (n_layers, to, ti, p, batch_block))
 
 
 def _deep_plane_specs(n, n_layers, to, ti, batch_block):
     p = n // 2
-    x_plane = pl.BlockSpec((ti, batch_block, p), lambda b: (0, b, 0))
-    o_plane = pl.BlockSpec((to, batch_block, p), lambda b: (0, b, 0))
-    stage = pl.BlockSpec((n_layers, to, ti, batch_block, p),
-                         lambda b: (0, 0, 0, b, 0))
+    x_plane = pl.BlockSpec((ti, p, batch_block), lambda b: (0, 0, b))
+    o_plane = pl.BlockSpec((to, p, batch_block), lambda b: (0, 0, b))
+    stage = pl.BlockSpec((n_layers, to, ti, p, batch_block),
+                         lambda b: (0, 0, 0, 0, b))
     return x_plane, o_plane, stage
 
 
@@ -1026,7 +1039,7 @@ def deepgrid_pallas_call(n: int, n_layers: int, to: int, ti: int,
                                           batch_block)
     x_plane, o_plane, _ = _deep_plane_specs(n, n_layers, to, ti, batch_block)
     n_out = 2 if detect_last else 4
-    out_shape = [jax.ShapeDtypeStruct((to, b_total, p), jnp.float32)] * n_out
+    out_shape = [jax.ShapeDtypeStruct((to, p, b_total), jnp.float32)] * n_out
     flops = _deep_flops_per_block(n, n_layers, to, ti, n_cols, batch_block)
     return pl.pallas_call(
         functools.partial(deepgrid_kernel, detect_last=detect_last),
@@ -1061,8 +1074,8 @@ def deepgrid_fwd_pallas_call(n: int, n_layers: int, to: int, ti: int,
                                                 batch_block)
     n_out = 2 if detect_last else 4
     out_shape = (
-        [jax.ShapeDtypeStruct((to, b_total, p), jnp.float32)] * n_out
-        + [jax.ShapeDtypeStruct((n_layers, to, ti, b_total, p),
+        [jax.ShapeDtypeStruct((to, p, b_total), jnp.float32)] * n_out
+        + [jax.ShapeDtypeStruct((n_layers, to, ti, p, b_total),
                                 jnp.float32)] * 8)
     flops = _deep_flops_per_block(n, n_layers, to, ti, n_cols, batch_block)
     return pl.pallas_call(
@@ -1101,7 +1114,7 @@ def deepgrid_bwd_pallas_call(n: int, n_layers: int, to: int, ti: int,
     out_shape = (
         [jax.ShapeDtypeStruct(coef, jnp.float32)] * 2
         + [jax.ShapeDtypeStruct(gains, jnp.float32)]
-        + [jax.ShapeDtypeStruct((ti, b_total, p), jnp.float32)] * 4)
+        + [jax.ShapeDtypeStruct((ti, p, b_total), jnp.float32)] * 4)
     # inverse state recompute + adjoint cotangent + coefficient grads
     flops = 3 * _deep_flops_per_block(n, n_layers, to, ti, n_cols,
                                       batch_block)

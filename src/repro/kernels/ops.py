@@ -63,6 +63,11 @@ TRACE_COUNTS = {"mesh_apply": 0, "rfnn_linear": 0, "rfnn_network": 0,
                 "tiled_apply": 0, "tiled_apply_sharded": 0, "deep_apply": 0,
                 "deep_apply_sharded": 0}
 
+#: Instrumentation: ``(block rows, grid steps)`` of the batch grid of the
+#: last traced deep-grid call, keyed like :data:`TRACE_COUNTS` (the
+#: sharded entry records one device's shard).  Written at trace time only.
+DEEP_BLOCKS: dict[str, tuple[int, int]] = {}
+
 #: Instrumentation: number of coefficient-pack builds actually executed by
 #: :func:`pack_deep_grid` (cache misses / tracer bypasses), keyed by the
 #: entry point that requested the pack.  Steady-state serving must not
@@ -82,24 +87,28 @@ def default_interpret() -> bool:
     return backend == "cpu"
 
 
-def _auto_block(b: int, block_b: int) -> int:
+def _auto_block(b: int, block_b: int, quantum: int = 8) -> int:
     """Shrink ``block_b`` to divide the batch evenly (never grow past it).
 
     ``block_b`` is a working-set ceiling, not a quantum: padding the
     batch up to a multiple of the raw ceiling can waste most of the last
     block (e.g. 256 rows in 232-row blocks -> 464 padded rows).
-    Spreading the same rows over ``ceil(b / block_b)`` equal blocks keeps
-    every block under the ceiling with at most 7 pad rows per block."""
+    Spreading the same rows over ``ceil(b / block_b)`` equal blocks, each
+    rounded up to ``quantum`` rows (8 sublanes for ``[B, P]`` planes, 128
+    lanes for ``[.., P, B]`` ones), keeps every block under the ceiling
+    with at most ``quantum - 1`` pad rows per block.  The ceiling itself
+    counts in whole quanta, at least one."""
     if b <= 0:
         return 1
-    block_b = max(1, block_b)
+    block_b = max(quantum, block_b // quantum * quantum)
     if b <= block_b + block_b // 8:
         # anti-fragmentation: a single block may overshoot the ceiling by
-        # <= 1/8 (the target itself sits well under the physical budget)
-        return max(1, -(-b // 8) * 8)
+        # <= 1/8 (the target itself sits well under the physical budget);
+        # it spans the whole padded batch, so whole sublane rows suffice
+        return -(-b // 8) * 8
     n_blocks = -(-b // block_b)
     even = -(-b // n_blocks)                       # ceil(b / n_blocks)
-    return max(1, min(block_b, -(-even // 8) * 8))
+    return -(-even // quantum) * quantum
 
 
 def _pad_batch(x2d: Array, block: int) -> tuple[Array, int]:
@@ -434,13 +443,13 @@ def _deepgrid_planes_bwd(deep, block_b, nb, interpret, detect_last, res,
         block_b, nb, detect_last, interpret)
     pv, pu = deep_grid_parity_arrays(deep)
     dcv, dcu, dg, dxer, dxei, dxor, dxoi = call(
-        givens_mesh.inverse_coefficients(coef_v),
-        givens_mesh.adjoint_coefficients(coef_v), pv,
-        givens_mesh.inverse_coefficients(coef_u),
-        givens_mesh.adjoint_coefficients(coef_u), pu,
+        givens_mesh.inverse_coefficients(coef_v, axis=-1),
+        givens_mesh.adjoint_coefficients(coef_v, axis=-1), pv,
+        givens_mesh.inverse_coefficients(coef_u, axis=-1),
+        givens_mesh.adjoint_coefficients(coef_u, axis=-1), pu,
         gains, *xplanes, *stages, *cot)
     # the combine's transpose (sum of each input tile's cotangent over the
-    # To rows) already ran inside the kernel — dx comes back as [B, Ti, P]
+    # To rows) already ran inside the kernel — dx comes back as [Ti, P, B]
     return dcv, dcu, dg, dxer, dxei, dxor, dxoi
 
 
@@ -449,12 +458,13 @@ _deepgrid_planes.defvjp(_deepgrid_planes_fwd, _deepgrid_planes_bwd)
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _pack_deep_grid_impl(deep, hardware, layers):
-    """Stacked [L, To, Ti, C, 8, P] coefficients + [L, To, Ti, 12, P]
+    """Stacked [L, To, Ti, C, P, 8] coefficients + [L, To, Ti, P, 12]
     gains for the deep megakernel, each tile's columns gathered onto the
     aligned kernel columns (identity cells where it sits a column out
     and in the padding).  Per-tile gains use the layer layout (g0 input
     screens, g1 attenuation + folded mid screens, g2 digital scale +
-    output screen)."""
+    output screen).  The row index is the last axis: the kernel reads
+    each row as a [P, 1] column along the pair-slot sublanes."""
     src = deep_grid_column_sources(deep)               # [2, L, To, Ti, C]
     # Tiles that share their schedules, column alignment and argument
     # structure pack as ONE vmapped computation, so the traced program
@@ -473,11 +483,13 @@ def _pack_deep_grid_impl(deep, hardware, layers):
 
         def one(ta, sv=sv, su=su, sv_src=src[0, l0, o0, i0],
                 su_src=src[1, l0, o0, i0]):
-            return (align_columns(_mesh_coefficients(
+            return (jnp.swapaxes(align_columns(_mesh_coefficients(
                         sv, ta["v"], hardware, ta.get("key_v")), sv_src),
-                    align_columns(_mesh_coefficients(
+                        -1, -2),
+                    jnp.swapaxes(align_columns(_mesh_coefficients(
                         su, ta["u"], hardware, ta.get("key_u")), su_src),
-                    _layer_gains(deep.n, ta))
+                        -1, -2),
+                    _layer_gains(deep.n, ta).T)
 
         stacked = jax.tree.map(lambda *a: jnp.stack(a),
                                *(layers[l][o][i] for l, o, i in positions))
@@ -564,8 +576,8 @@ def pack_deep_grid(layers, *, n: int, plans=None,
 
     Returns ``(deep, (coef_v, coef_u, gains))``: the static
     :class:`~repro.kernels.schedule.DeepGridSchedule` plus the stacked
-    ``[L, To, Ti, C, 8, P]`` coefficient tensors and
-    ``[L, To, Ti, 12, P]`` gain rows, identity-padded to the
+    ``[L, To, Ti, C, P, 8]`` coefficient tensors and
+    ``[L, To, Ti, P, 12]`` gain rows, identity-padded to the
     network-wide column count — ready for :func:`deep_apply`'s
     ``packed=``.  Results go through the leaf-identity pack cache
     (``PACK_EVENTS``): repeat calls with the same (immutable) tile
@@ -594,28 +606,28 @@ _VMEM_TARGET = 4 * 1024 * 1024
 
 def _vmem_auto_block(b: int, block_b: int | None, n: int,
                      planes_per_row: int, interpret: bool) -> int:
-    """The one VMEM-budget batch-block helper (every kernel's auto-block
-    is this function with its own plane count).
+    """Batch rows per grid step of a deep-grid call (planes [.., P, B]).
 
-    ``None`` sizes the block so ``planes_per_row`` [block, P] f32 planes
-    fit the target, then shrinks for small batches.  On the chip a
-    plane's P lanes occupy whole 128-lane tiles (see
-    ``givens_mesh.vmem_bytes``), so a 16-channel tile's 8-lane plane
-    costs 16x its data and deep grids get blocks of a few sublane rows.
-    The interpreter has no tiled VMEM: there the data bytes set the
-    block, and a block that fits a host cache rides through all L
-    layers while hot.
+    ``None`` sizes the block so ``planes_per_row`` [P, block] f32 planes
+    fit the target.  On the chip a plane's P pair slots occupy whole
+    8-sublane tiles (see ``givens_mesh.vmem_bytes``), so a row costs
+    ``ceil(P / 8) * 8 * 4`` bytes a plane; the interpreter has no tiled
+    VMEM, and there the data bytes set the block.  The batch rides the
+    lanes, so :func:`_auto_block` splits it in quanta of 128 rows: a
+    batch that fits runs whole in one grid step (a block as long as the
+    padded batch needs no lane alignment), a larger one in even blocks
+    of a multiple of 128 rows.  An explicit ``block_b`` below 128 counts
+    as 128.
     """
     if block_b is None:
         p = n // 2
-        lanes = p if interpret else -(-p // 128) * 128
-        per_row = planes_per_row * lanes * 4
-        block_b = max(8, min(1024, _VMEM_TARGET // per_row // 8 * 8))
-    return _auto_block(b, block_b)
+        sublanes = p if interpret else -(-p // 8) * 8
+        block_b = _VMEM_TARGET // (planes_per_row * sublanes * 4)
+    return _auto_block(b, block_b, quantum=128)
 
 
 def _deep_planes_per_row(deep) -> int:
-    """Resident [block, P] planes per batch row for the deep kernel: 8
+    """Resident [P, block] planes per batch row for the deep kernel: 8
     stage-residual planes per tile per layer, 4 input and 4 output planes
     per tile column / row slot, ~4 working planes.  Reduces to the
     network kernel's ``8 L + 12`` at To = Ti = 1."""
@@ -624,10 +636,10 @@ def _deep_planes_per_row(deep) -> int:
 
 
 def _split_tile_planes(xt):
-    """[B, Ti, n] complex -> 4 de-interleaved [Ti, B, P] f32 planes (the
-    kernel's tile-major layout)."""
-    xt = jnp.swapaxes(xt, 0, 1)
-    xe, xo = xt[..., 0::2], xt[..., 1::2]
+    """[B, Ti, n] complex -> 4 de-interleaved [Ti, P, B] f32 planes (the
+    kernel's tile-major layout, batch on lanes)."""
+    xt = jnp.transpose(xt, (1, 2, 0))
+    xe, xo = xt[:, 0::2], xt[:, 1::2]
     return (jnp.real(xe).astype(jnp.float32),
             jnp.imag(xe).astype(jnp.float32),
             jnp.real(xo).astype(jnp.float32),
@@ -635,9 +647,9 @@ def _split_tile_planes(xt):
 
 
 def _merge_deep_out(outs, detect_last, to, n, b_orig, batch_shape):
-    """Kernel output planes [To, B, P] -> [..., To*n] (real magnitudes or
+    """Kernel output planes [To, P, B] -> [..., To*n] (real magnitudes or
     complex)."""
-    outs = [jnp.swapaxes(t, 0, 1) for t in outs]   # [B, To, P]
+    outs = [jnp.transpose(t, (2, 0, 1)) for t in outs]   # [B, To, P]
     if detect_last:
         oe, oo = outs
         y = jnp.stack([oe, oo], axis=-1).reshape((-1, to * n))[:b_orig]
@@ -660,6 +672,7 @@ def _deep_apply_impl(deep, block_b, interpret, detect_last, trace_key,
                           _deep_planes_per_row(deep), interpret)
     xt, b_orig = _pad_batch(xt, bb)
     nb = xt.shape[0] // bb
+    DEEP_BLOCKS[trace_key] = (bb, nb)
     outs = _deepgrid_planes(deep, bb, nb, interpret, detect_last,
                             coef_v, coef_u, gains, *_split_tile_planes(xt))
     return _merge_deep_out(outs, detect_last, to, n, b_orig, batch_shape)
@@ -789,10 +802,10 @@ def _deepgrid_planes_sharded_bwd(deep, layer, mesh, row_axis, data_axis,
             deep.n, 1, to_local, deep.ti, deep.n_columns, block_b, nb,
             detect_last, interpret)
         dcv, dcu, dg, dxer, dxei, dxor, dxoi = call(
-            givens_mesh.inverse_coefficients(cv),
-            givens_mesh.adjoint_coefficients(cv), pv,
-            givens_mesh.inverse_coefficients(cu),
-            givens_mesh.adjoint_coefficients(cu), pu,
+            givens_mesh.inverse_coefficients(cv, axis=-1),
+            givens_mesh.adjoint_coefficients(cv, axis=-1), pv,
+            givens_mesh.inverse_coefficients(cu, axis=-1),
+            givens_mesh.adjoint_coefficients(cu, axis=-1), pu,
             g, xer, xei, xor, xoi, *rest)
         # the kernel already summed its local rows' input-cotangent
         # partials; the psum over the row axis completes the transpose of
@@ -839,6 +852,7 @@ def _deep_apply_sharded_impl(deep, mesh, row_axis, data_axis, block_b,
     # every device's batch shard must tile into whole blocks
     xt, b_orig = _pad_batch(xt, bb * n_data)
     nb = xt.shape[0] // n_data // bb
+    DEEP_BLOCKS[trace_key] = (bb, nb)
     planes = _split_tile_planes(xt)
     outs = None
     for l in range(deep.n_layers):
@@ -892,6 +906,8 @@ def deep_apply(layers, x: Array, *, n: int, plans=None,
     cannot be evicted out from under it by other users of the shared
     cache.  ``block_b=None`` sizes the batch block to the kernel's VMEM
     target (large blocks for small grids, shrinking with n, L, To, Ti).
+    The planes carry the batch on lanes, so an explicit ``block_b`` is a
+    ceiling in whole 128-row lane tiles: a value below 128 counts as 128.
 
     ``mesh``: an optional 2-axis ``jax.sharding.Mesh`` — each layer's
     grid then shards over ``(row_axis, data_axis)`` via shard_map: tile
@@ -946,7 +962,7 @@ def pack_network(layers, *, n: int, plans=None,
     optional ``scale``/``key_v``/``key_u``) and ``plans`` a flat
     per-layer sequence of ``(v_plan, u_plan)`` pairs.  Returns
     ``(deep, (coef_v, coef_u, gains))`` in the deep-grid layout
-    (``[L, 1, 1, C, 8, P]`` coefficients), ready for
+    (``[L, 1, 1, C, P, 8]`` coefficients), ready for
     :func:`rfnn_network`'s ``packed=``.  Pack-cache semantics are
     :func:`pack_deep_grid`'s, ticking ``PACK_EVENTS["rfnn_network"]``.
     """
@@ -972,6 +988,7 @@ def rfnn_network(layers, x: Array, *, n: int,
     forward and one backward for the whole network, inter-layer
     activations never leaving VMEM, packing cached per (schedule, param
     identity) so serving steady state does zero packing work.
+    ``block_b`` is :func:`deep_apply`'s (at least 128 rows).
     """
     KERNEL_PATH_CALLS["rfnn_network"] += 1
     if packed is None:
@@ -989,7 +1006,7 @@ def pack_tile_grid(tiles, *, n: int, plans=None,
     nested ``[To][Ti]`` sequence of per-tile dicts and ``plans`` a
     matching nesting of ``(v_plan, u_plan)`` pairs.  Returns
     ``(deep, (coef_v, coef_u, gains))`` in the deep-grid layout
-    (``[1, To, Ti, C, 8, P]`` coefficients), ready for
+    (``[1, To, Ti, C, P, 8]`` coefficients), ready for
     :func:`tiled_apply`'s ``packed=``.  Pack-cache semantics are
     :func:`pack_deep_grid`'s, ticking ``PACK_EVENTS["tiled_apply"]``.
     """
@@ -1017,7 +1034,8 @@ def tiled_apply(tiles, x: Array, *, n: int, plans=None,
     output ``[..., To*n]`` — the matched-line power combiner sums the Ti
     tile outputs of each row coherently in VMEM, and the readout mode
     (|.| detection, real part) plus detector noise compose on top,
-    outside the kernel.
+    outside the kernel.  ``block_b`` is :func:`deep_apply`'s (at least
+    128 rows).
     """
     KERNEL_PATH_CALLS["tiled_apply"] += 1
     if packed is None:

@@ -180,7 +180,7 @@ class DeepGridSchedule:
     ``layers[l][o][i]`` is the ``(V, U)`` pair of :class:`MeshSchedule`\\ s
     of tile ``(o, i)`` in layer ``l``.  The deep megakernel runs the whole
     network in one VMEM residency with coefficient/gain tensors stacked
-    to ``[L, To, Ti, C, 8, P]`` / ``[L, To, Ti, 12, P]`` and one ``[L, C]``
+    to ``[L, To, Ti, C, P, 8]`` / ``[L, To, Ti, P, 12]`` and one ``[L, C]``
     parity table per mesh, where ``C = n_columns`` counts the columns of
     every layer's tile schedules aligned to shared parities (identity
     cells fill a tile's sat-out and padding columns — exact no-ops in

@@ -150,8 +150,8 @@ class TileGridShardSpecs:
     The tile-grid kernel's pallas grid is (tile rows x batch blocks); the
     distributed layout shards the *work* over both mesh axes:
 
-      * ``coef`` — the ``[L, To, Ti, C, 8, P]`` coefficient stacks (and
-        the ``[L, C]`` parity tables / ``[L, To, Ti, 12, P]`` gains) of
+      * ``coef`` — the ``[L, To, Ti, C, P, 8]`` coefficient stacks (and
+        the ``[L, C]`` parity tables / ``[L, To, Ti, P, 12]`` gains) of
         the deep-grid layout: REPLICATED.  Each device slices
         its own tile-row slab (axis 1) in-body (``axis_index`` over the
         row axis).  They are small, and feeding them row-partitioned
@@ -159,14 +159,15 @@ class TileGridShardSpecs:
         stacks are traced (built by concatenate under an enclosing jit,
         e.g. ``jit(grad(...))`` over unpacked tiles) — see the note in
         ``repro.kernels.ops``;
-      * ``x_plane`` — the ``[Ti, B, P]`` input planes: batch-split,
-        replicated over tile rows (every row sweeps the whole input);
-      * ``o_plane`` — the ``[To, B, P]`` combined row outputs: split on
+      * ``x_plane`` — the ``[Ti, P, B]`` input planes (batch on lanes):
+        batch-split, replicated over tile rows (every row sweeps the
+        whole input);
+      * ``o_plane`` — the ``[To, P, B]`` combined row outputs: split on
         both axes (each device owns its rows' outputs for its batch);
-      * ``stage`` — the ``[L, To, Ti, B, P]`` VJP stage residuals (the
+      * ``stage`` — the ``[L, To, Ti, P, B]`` VJP stage residuals (the
         kernel's tile-major layout): batch and tile rows both split,
-        layer and input-tile axes whole;
-      * ``dx_plane`` — the ``[Ti, B, P]`` input cotangent *after* the
+        layer, input-tile and pair-slot axes whole;
+      * ``dx_plane`` — the ``[Ti, P, B]`` input cotangent *after* the
         cross-device ``psum`` over the row axis (the matched-line
         combiner's transpose): batch-split, replicated over rows.
 
@@ -184,13 +185,15 @@ class TileGridShardSpecs:
 
 def tile_grid_shard_specs(row_axis: str = "rows",
                           data_axis: str = "data") -> TileGridShardSpecs:
-    """The canonical (tile-row x batch) sharding of the tile-grid kernel."""
+    """The canonical (tile-row x batch) sharding of the tile-grid kernel,
+    whose planes carry the batch on lanes (``[.., P, B]``, data axis
+    last)."""
     return TileGridShardSpecs(
         coef=P(),
-        x_plane=P(None, data_axis),
-        o_plane=P(row_axis, data_axis),
-        stage=P(None, row_axis, None, data_axis),
-        dx_plane=P(None, data_axis),
+        x_plane=P(None, None, data_axis),
+        o_plane=P(row_axis, None, data_axis),
+        stage=P(None, row_axis, None, None, data_axis),
+        dx_plane=P(None, None, data_axis),
     )
 
 

@@ -161,26 +161,53 @@ def test_deepgrid_mixed_reck_plans_identity_padding():
 # ragged batches
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("batch", [1, 7, 130])
+@pytest.mark.parametrize("batch", [1, 7, 64, 127, 129, 130, 200, 1024])
 def test_deepgrid_ragged_batches(batch):
     """B need not divide the batch block: the tail block's zero-padded
     rows must stay exactly zero through every in-kernel detection (the
-    zero-guarded |z| pullback) in forward and VJP."""
+    zero-guarded |z| pullback) in forward and VJP.  Under a 128-row
+    ceiling (one lane tile of the batch-on-lanes planes) up to 144 rows
+    run whole in one grid step of whole sublane rows, 200 in two padded
+    128-row blocks, 1024 in eight."""
     n, depth, g = 4, 2, 2
+    rows = ops._vmem_auto_block(batch, 128, n, 1, interpret=True)
+    assert rows == (-(-batch // 8) * 8 if batch <= 144 else 128)
     layers = _make_deep(n, depth, g, g, seed=2)
     x = _rand_x(g * n, batch)
     y_pl = _per_layer(layers, x, n)
-    y_k = ops.deep_apply(layers, x, n=n, block_b=64)
+    y_k = ops.deep_apply(layers, x, n=n, block_b=128)
     assert y_k.shape == (batch, g * n)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_pl), atol=1e-5)
 
     w = 1.0 + jnp.arange(g * n, dtype=jnp.float32)
     g_k = jax.grad(lambda ls: jnp.sum(
-        ops.deep_apply(ls, x, n=n, block_b=64) * w))(layers)
+        ops.deep_apply(ls, x, n=n, block_b=128) * w))(layers)
     g_pl = jax.grad(lambda ls: jnp.sum(
         jnp.abs(_per_layer(ls, x, n)) * w))(layers)
     assert all(bool(jnp.all(jnp.isfinite(l))) for l in jax.tree.leaves(g_k))
     assert _max_rel_err(g_k, g_pl) <= REL_TOL
+
+
+@pytest.mark.parametrize("batch, block", [(1024, (128, 8)), (64, (64, 1))])
+def test_deepgrid_block_rows_and_grid_steps(batch, block):
+    """The chip's block arithmetic for the 64-wide L=4 grid of 16x16 tiles
+    (548 planes a row, P = 8 sublanes x 4 B each under the 4 MiB target):
+    a 1024-row training batch runs in 8 grid steps of 128 rows, a 64-row
+    serving panel whole in one.  Traced for the chip (``interpret=False``)
+    without compiling, so the recorded block is the one a v5e runs."""
+    n, depth, g = 16, 4, 4
+    deep = deep_grid_schedule(n, depth, g, g, None)
+    rows = ops._vmem_auto_block(batch, None, n, ops._deep_planes_per_row(deep),
+                                interpret=False)
+    assert (rows, -(-batch // rows)) == block
+    layers = jax.tree.map(lambda a: jax.ShapeDtypeStruct(jnp.shape(a),
+                                                         jnp.result_type(a)),
+                          _make_deep(n, depth, g, g))
+    x = jax.ShapeDtypeStruct((batch, g * n), jnp.float32)
+    ops._deep_apply_impl.clear_cache()  # a cached trace records nothing
+    jax.eval_shape(lambda ls, xx: ops.deep_apply(ls, xx, n=n,
+                                                 interpret=False), layers, x)
+    assert ops.DEEP_BLOCKS["deep_apply"] == block
 
 
 # ---------------------------------------------------------------------------
@@ -289,17 +316,21 @@ def test_lower_deep_matches_per_layer_compiled_apply():
                                atol=1e-5 * float(jnp.max(jnp.abs(y))))
 
 
-def test_deep_apply_ref_matches_compiled_deep_program():
+@pytest.mark.parametrize("tile", [4, 8, 16, 32])
+def test_deep_apply_ref_matches_compiled_deep_program(tile):
     """The pure-jnp deep oracle (the chip smoke test's reference) agrees
     with the kernel on a calibrated Reck deep program — hardware model,
     per-tile noise keys and mixed-parity column alignment included — and
-    its autodiff gradients match the kernel VJP on trainable tiles."""
+    its autodiff gradients match the kernel VJP on trainable tiles.  The
+    tile sizes put P = 2 ... 16 pair slots on the planes' sublanes: less
+    than, exactly and more than one 8-sublane tile."""
     from repro import compile as comp
     from repro.kernels import ref
     from repro.paper.prototype import PROTOTYPE
 
     rng = np.random.default_rng(4)
-    tile, depth, d = 4, 2, 8
+    depth = 2
+    d = 2 * tile
     tps = []
     for l in range(depth):
         tp = _deep_progs([rng.normal(size=(d, d)).astype(np.float32)],

@@ -74,12 +74,13 @@ def _deep_layers(n=16, depth=4, g=4):
         for _ in range(g)) for _ in range(g)) for _ in range(depth))
 
 
-@pytest.mark.parametrize("batch", [256, 1024])
+@pytest.mark.parametrize("batch", [64, 256, 1024])
 @pytest.mark.parametrize("kind", ["inference", "vjp"])
 def test_deep_grid_kernels_compile_for_v5e(one_chip, compiled_kernels,
                                            batch, kind):
     """64x64, L=4, 16x16 tiles at the block ``deep_apply`` picks for the
-    batch: the inference kernel, and the VJP's forward and backward."""
+    batch: the inference kernel, and the VJP's forward and backward.  64
+    rows is the serving panel, run whole in one grid step."""
     layers = _shapes(_deep_layers(), one_chip)
     x = jax.ShapeDtypeStruct((batch, 64), jnp.float32, sharding=one_chip)
 
