@@ -1,0 +1,10 @@
+"""The row-blocked deep-grid forward kernel of training: one layer of one
+chip's tile rows, swept a block of tile rows at a time.  Its work is the
+resident forward's (``deepgrid_fwd_kernel``), counted per call with that
+chip's dims: ``layers`` 1, ``to`` its tile rows, ``batch`` its rows."""
+
+from counts.deepgrid_fwd_kernel import count  # noqa: F401
+
+#: the names a v5e trace gives this kernel's calls (the ``name=`` of its
+#: ``pallas_call``)
+TRACE_NAMES = ('deepgrid_rows_fwd_kernel',)

@@ -266,6 +266,29 @@ def inverse_coefficients(coef: jax.Array, eps: float = 1e-12,
     return out.astype(coef.dtype)
 
 
+def _adjoint_rows(r):
+    """A cell's 8 adjoint rows from its 8 rows (``r``: one array a row):
+    :func:`adjoint_coefficients` for a kernel that holds the rows."""
+    return [r[0], -r[1], r[4], -r[5], r[2], -r[3], r[6], -r[7]]
+
+
+def _inverse_rows(r, eps: float = 1e-12):
+    """A cell's 8 inverse rows from its 8 rows: the arithmetic of
+    :func:`inverse_coefficients` written out in reals, for a kernel that
+    holds the rows (Mosaic has no complex type)."""
+    r0, r1, r2, r3, r4, r5, r6, r7 = r
+    dr = (r0 * r6 - r1 * r7) - (r2 * r4 - r3 * r5)
+    di = (r0 * r7 + r1 * r6) - (r2 * r5 + r3 * r4)
+    mag2 = jnp.maximum(dr * dr + di * di, eps)
+    qr, qi = dr / mag2, -di / mag2                 # conj(det) / |det|^2
+
+    def times_q(ar, ai):
+        return [ar * qr - ai * qi, ar * qi + ai * qr]
+
+    return (times_q(r6, r7) + times_q(-r2, -r3) + times_q(-r4, -r5)
+            + times_q(r0, r1))
+
+
 def _conj_dot(xr, xi, gr, gi):
     """Batch-summed conj(x) * g — one complex coefficient gradient entry."""
     return (jnp.sum(xr * gr + xi * gi, axis=0, keepdims=True),
@@ -536,9 +559,12 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # schedule of ``repro.kernels.schedule.DeepGridSchedule``: every column
 # has ONE parity across a layer's tiles (an SMEM ``[L, C]`` table per
 # mesh), tiles whose own schedules disagree sit the column out as an
-# identity cell.  The pallas grid is the batch alone — inter-layer
-# re-detection needs every row of a layer, so one grid step carries one
-# batch block through the entire network.
+# identity cell.  The pallas grid of a multi-layer call is the batch
+# alone — inter-layer re-detection needs every row of a layer, so one
+# grid step carries one batch block through the entire network.  A
+# single-layer call whose stacks outgrow VMEM (a wide grid, or one
+# device's row slab of it) adds a leading grid axis over blocks of tile
+# rows; see "Row blocks" below.
 #
 # Layout: planes are [To, Ti, P, B] — tile axes leading, the P = n/2 pair
 # slots on sublanes and the batch block on lanes (the single-mesh and
@@ -595,6 +621,27 @@ def rfnn_linear_bwd_pallas_call(n: int, n_cols_v: int, n_cols_u: int,
 # multiple of 128 lanes, or the whole padded batch when that fits), and a
 # call whose count exceeds the default scoped limit raises
 # ``vmem_limit_bytes`` to fit.
+#
+# Row blocks.  A (P, 8) slab per tile and column makes a coefficient
+# stack 4 KiB x To x Ti x C in VMEM: one 1024-wide layer of 16x16 tiles
+# (64 x 64 tiles, 29 columns) holds 486 MB per stack, past any VMEM.  A
+# single-layer call then runs on a (row blocks, batch blocks) grid
+# (:func:`deep_row_block` picks the rows): each grid step sweeps
+# ``row_block`` tile rows against the whole input, and the row block's
+# coefficients, gains, output rows and stage planes are blocks indexed
+# by the row.  Rows are the outer axis, so a row block's coefficient
+# blocks change only between row blocks (fetched once, into one buffer)
+# and its coefficient-gradient blocks accumulate over consecutive batch
+# steps (Pallas keeps a revisited output block in VMEM only across
+# consecutive steps).  The input cotangent sums over rows, which are not
+# consecutive: each row block writes its partial sum ``[R, Ti, P, B]``
+# and the caller adds the R partials.  The backward takes the
+# coefficients themselves and derives each mesh's inverse into VMEM
+# scratch at a row block's first batch step (the adjoint is a sign flip
+# and a swap of rows, taken per column): worked out by XLA, the inverse
+# and adjoint stacks of a 1024-wide layer take gigabytes of HBM in the
+# padded (8, 128) layout.  One row block is exactly the resident call
+# above, which takes the inverse and adjoint stacks as operands.
 
 #: Mosaic's default scoped-VMEM limit on v5e.
 _SCOPED_VMEM_DEFAULT = 16 * 2**20
@@ -611,12 +658,12 @@ def vmem_bytes(shape) -> int:
     return math.prod(lead) * (-(-sub // 8) * 8) * (-(-lane // 128) * 128) * 4
 
 
-def _deep_compiler_params(resident, blocked):
+def _deep_compiler_params(resident, blocked, headroom=_VMEM_HEADROOM):
     """``vmem_limit_bytes`` for a deep-grid call whose resident buffers
     (one copy) and batch-blocked buffers (double-buffered) need more than
     the default scoped VMEM; ``None`` when the default suffices."""
     need = (sum(vmem_bytes(s) for s in resident)
-            + 2 * sum(vmem_bytes(s) for s in blocked) + _VMEM_HEADROOM)
+            + 2 * sum(vmem_bytes(s) for s in blocked) + headroom)
     if need <= _SCOPED_VMEM_DEFAULT:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=need)
@@ -710,13 +757,28 @@ def _vcoef_grad_odd(s_in, g_out):
     return tuple(jnp.where(wrap, 0.0, r) for r in rows)
 
 
-def _vrun_columns_bwd(ci_ref, ca_ref, par_ref, dco_ref, l, state, cot):
+def _stored_cells(inv_ref, adj_ref):
+    """Row source of a backward sweep whose inverse and adjoint stacks
+    are operands: ``(l, c) -> (inverse rows, adjoint rows)``."""
+    return lambda l, c: (_coef_rows(inv_ref, l, c), _coef_rows(adj_ref, l, c))
+
+
+def _derived_cells(inv_ref, coef_ref):
+    """Row source of a row-blocked backward: inverse rows from the
+    block's VMEM scratch, adjoint rows from the coefficients (a sign
+    flip and a swap of rows)."""
+    return lambda l, c: (_coef_rows(inv_ref, l, c),
+                         _adjoint_rows(_coef_rows(coef_ref, l, c)))
+
+
+def _vrun_columns_bwd(cells, par_ref, dco_ref, l, state, cot):
     """Reversed stacked-tile sweep of layer ``l``'s mesh: recompute
-    states via the per-cell inverse slab, accumulate each column's
+    states via the per-cell inverse rows, accumulate each column's
     coefficient gradient (its 8 lane-reduced rows joined along the lanes
     into one [To, Ti, P, 8] slab) into its slot of the revisited
-    ``dco_ref``, propagate the cotangent via the adjoint slab."""
-    n_cols = ci_ref.shape[3]
+    ``dco_ref``, propagate the cotangent via the adjoint rows.
+    ``cells(l, c)`` gives a column's (inverse, adjoint) rows."""
+    n_cols = dco_ref.shape[3]
 
     def branch(step, coef_grad, ci, ca):
         def run(sg):
@@ -728,7 +790,7 @@ def _vrun_columns_bwd(ci_ref, ca_ref, par_ref, dco_ref, l, state, cot):
 
     def body(k, carry):
         c = n_cols - 1 - k
-        ci, ca = _coef_rows(ci_ref, l, c), _coef_rows(ca_ref, l, c)
+        ci, ca = cells(l, c)
         out = jax.lax.cond(par_ref[l, c] != 0,
                            branch(_vcolumn_odd, _vcoef_grad_odd, ci, ca),
                            branch(_vcolumn_even, _vcoef_grad_even, ci, ca),
@@ -787,9 +849,8 @@ def _detect_bwd_z(z, goe, goo):
     return inv_e * zer, inv_e * zei, inv_o * zor, inv_o * zoi
 
 
-def _layer_linear_bwd(cv_inv_ref, cv_adj_ref, par_v_ref, cu_inv_ref,
-                      cu_adj_ref, par_u_ref, dcv_ref, dcu_ref, dg_ref, l, g,
-                      x_in, v, u, gz):
+def _layer_linear_bwd(cells_v, par_v_ref, cells_u, par_u_ref, dcv_ref,
+                      dcu_ref, dg_ref, l, g, x_in, v, u, gz):
     """Unwind the linear stages g2 -> U -> g1 -> V -> g0 of stacked layer
     ``l`` — every (To, Ti) tile at once.
 
@@ -808,16 +869,16 @@ def _layer_linear_bwd(cv_inv_ref, cv_adj_ref, par_v_ref, cu_inv_ref,
     guer, guei = _cmul(g[8], -g[9], gzer, gzei)
     guor, guoi = _cmul(g[10], -g[11], gzor, gzoi)
 
-    _, gh = _vrun_columns_bwd(cu_inv_ref, cu_adj_ref, par_u_ref, dcu_ref, l,
-                              u, (guer, guei, guor, guoi))
+    _, gh = _vrun_columns_bwd(cells_u, par_u_ref, dcu_ref, l, u,
+                              (guer, guei, guor, guoi))
 
     dg1 = (_vconj_dot(v[0], v[1], gh[0], gh[1])
            + _vconj_dot(v[2], v[3], gh[2], gh[3]))
     gver, gvei = _cmul(g[4], -g[5], gh[0], gh[1])
     gvor, gvoi = _cmul(g[6], -g[7], gh[2], gh[3])
 
-    _, gs0 = _vrun_columns_bwd(cv_inv_ref, cv_adj_ref, par_v_ref, dcv_ref, l,
-                               v, (gver, gvei, gvor, gvoi))
+    _, gs0 = _vrun_columns_bwd(cells_v, par_v_ref, dcv_ref, l, v,
+                               (gver, gvei, gvor, gvoi))
 
     # pre-gain g0: s0 = g0 * x_in
     dg0 = (_vconj_dot(x_in[0], x_in[1], gs0[0], gs0[1])
@@ -909,10 +970,41 @@ def deepgrid_fwd_kernel(coef_v_ref, par_v_ref, coef_u_ref, par_u_ref,
 
 def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
                         cu_inv_ref, cu_adj_ref, par_u_ref, gains_ref,
-                        xer_ref, xei_ref, xor_ref, xoi_ref,
-                        sver_ref, svei_ref, svor_ref, svoi_ref,
-                        suer_ref, suei_ref, suor_ref, suoi_ref,
-                        *cot_and_out_refs, detect_last: bool):
+                        *refs, detect_last: bool):
+    """Unwind the whole deep grid in one residency, layers in reverse
+    (see :func:`_deep_backward`), from the inverse and adjoint
+    coefficient stacks of both meshes."""
+    _deep_backward(_stored_cells(cv_inv_ref, cv_adj_ref), par_v_ref,
+                   _stored_cells(cu_inv_ref, cu_adj_ref), par_u_ref,
+                   gains_ref, refs, detect_last, batch_axis=0)
+
+
+def deepgrid_rows_bwd_kernel(cv_ref, par_v_ref, cu_ref, par_u_ref,
+                             gains_ref, *refs, detect_last: bool):
+    """The backward of one layer's block of tile rows (grid ``(row
+    blocks, batch blocks)``), from the coefficients themselves: at a row
+    block's first batch step each mesh's inverse rows are derived into
+    the VMEM scratch (the last two refs), and every batch step reads
+    them there."""
+    *refs, cv_inv_ref, cu_inv_ref = refs
+
+    @pl.when(pl.program_id(1) == 0)
+    def _derive():
+        for coef_ref, inv_ref in ((cv_ref, cv_inv_ref), (cu_ref, cu_inv_ref)):
+            def column(c, carry, coef_ref=coef_ref, inv_ref=inv_ref):
+                inv_ref[0, :, :, c] = jnp.concatenate(
+                    _inverse_rows(_coef_rows(coef_ref, 0, c)), axis=-1)
+                return carry
+
+            jax.lax.fori_loop(0, coef_ref.shape[3], column, 0)
+
+    _deep_backward(_derived_cells(cv_inv_ref, cv_ref), par_v_ref,
+                   _derived_cells(cu_inv_ref, cu_ref), par_u_ref,
+                   gains_ref, refs, detect_last, batch_axis=1)
+
+
+def _deep_backward(cells_v, par_v_ref, cells_u, par_u_ref, gains_ref, refs,
+                   detect_last: bool, batch_axis: int):
     """Unwind the whole deep grid in one residency, layers in reverse.
 
     Every tile unwinds g2 -> U -> g1 -> V -> g0 from its saved stage
@@ -924,20 +1016,26 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
     and crossing a layer boundary re-detects the previous layer's rows
     from their saved post-U states and converts the (real) cotangent
     through the |detect| backward.  Layer 0 writes the input cotangent
-    planes [Ti, P, B].
+    planes [Ti, P, B] (a row-blocked call: its rows' partial sum).
+    ``refs``: the input, stage and cotangent planes, then the outputs;
+    ``batch_axis`` is the grid axis of the batch blocks, over which the
+    gradient blocks accumulate.
     """
+    (xer_ref, xei_ref, xor_ref, xoi_ref,
+     sver_ref, svei_ref, svor_ref, svoi_ref,
+     suer_ref, suei_ref, suor_ref, suoi_ref, *cot_and_out_refs) = refs
     n_cot = 2 if detect_last else 4
     cot_refs = cot_and_out_refs[:n_cot]
     (dcv_ref, dcu_ref, dg_ref,
      dxer_ref, dxei_ref, dxor_ref, dxoi_ref) = cot_and_out_refs[n_cot:]
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(batch_axis) == 0)
     def _init():
         dcv_ref[...] = jnp.zeros(dcv_ref.shape, dcv_ref.dtype)
         dcu_ref[...] = jnp.zeros(dcu_ref.shape, dcu_ref.dtype)
         dg_ref[...] = jnp.zeros(dg_ref.shape, dg_ref.dtype)
 
-    n_layers, to = cv_inv_ref.shape[0], cv_inv_ref.shape[1]
+    n_layers, to = gains_ref.shape[0], gains_ref.shape[1]
 
     def saved_v(l):
         return (sver_ref[l], svei_ref[l], svor_ref[l], svoi_ref[l])
@@ -973,8 +1071,8 @@ def deepgrid_bwd_kernel(cv_inv_ref, cv_adj_ref, par_v_ref,
         # the row combine is a sum: every tile of a row sees the row's
         # cotangent ([To, 1, P, B] broadcast across the stacked sweep)
         gx = _layer_linear_bwd(
-            cv_inv_ref, cv_adj_ref, par_v_ref, cu_inv_ref, cu_adj_ref,
-            par_u_ref, dcv_ref, dcu_ref, dg_ref, l,
+            cells_v, par_v_ref, cells_u, par_u_ref, dcv_ref, dcu_ref,
+            dg_ref, l,
             _layer_gain_rows(gains_ref, l), state_in, saved_v(l),
             saved_u(l), tuple(t[:, None] for t in gz))
         # combine's transpose: each input tile sums its cotangent over
@@ -1021,39 +1119,149 @@ def _deep_shapes(n, n_layers, to, ti, n_cols, batch_block):
             (n_layers, to, ti, p, batch_block))
 
 
-def _deep_plane_specs(n, n_layers, to, ti, batch_block):
-    p = n // 2
-    x_plane = pl.BlockSpec((ti, p, batch_block), lambda b: (0, 0, b))
-    o_plane = pl.BlockSpec((to, p, batch_block), lambda b: (0, 0, b))
-    stage = pl.BlockSpec((n_layers, to, ti, p, batch_block),
-                         lambda b: (0, 0, 0, 0, b))
-    return x_plane, o_plane, stage
+#: VMEM a deep-grid call may give its coefficient, gain and gradient
+#: buffers before it is row-blocked (see "Row blocks"); on v5e the
+#: planes, Mosaic's scratch and this fit the 128 MiB of the core.
+COEF_VMEM_BUDGET = 64 * 2**20
+
+#: Room above the counted buffers for Mosaic's scratch in a row-blocked
+#: call: its sweep planes ``[rows, Ti, P, B]`` hold a whole grid row of
+#: tiles, and the sweep's temporaries spill to VMEM (one tile row of 64
+#: tiles at a 128-row block took 11.4 MiB on a v5e compile).
+_ROW_BLOCK_HEADROOM = 32 * 2**20
+
+
+def deep_coef_vmem(n: int, n_layers: int, rows: int, ti: int,
+                   n_cols: int, blocked: bool) -> int:
+    """VMEM of the backward's coefficient-class buffers for ``rows`` tile
+    rows: four coefficient stacks (a row-blocked call: two, and the two
+    inverse stacks it derives into scratch) and the gains in, two
+    coefficient and one gain gradient out.  A row-blocked call
+    (``blocked``) holds one copy of each input block, which changes only
+    between row blocks, and two of each output block."""
+    coef, gains, *_ = _deep_shapes(n, n_layers, rows, ti, n_cols, 1)
+    ins = 4 * vmem_bytes(coef) + vmem_bytes(gains)
+    outs = 2 * vmem_bytes(coef) + vmem_bytes(gains)
+    return ins + (2 if blocked else 1) * outs
+
+
+def deep_row_block(n: int, n_layers: int, to: int, ti: int,
+                   n_cols: int) -> int:
+    """Tile rows a grid step of a deep-grid call: all ``to`` rows (one
+    row block, the resident call) for a multi-layer call or when the
+    stacks fit :data:`COEF_VMEM_BUDGET`; else the most rows, dividing
+    ``to``, whose blocks fit it (at least one row)."""
+    if n_layers > 1 or (deep_coef_vmem(n, 1, to, ti, n_cols, False)
+                        <= COEF_VMEM_BUDGET):
+        return to
+    fits = [r for r in range(1, to + 1) if not to % r
+            and deep_coef_vmem(n, 1, r, ti, n_cols, True)
+            <= COEF_VMEM_BUDGET]
+    return max(fits, default=1)
+
+
+class _DeepGrid:
+    """Grid, block specs and VMEM block shapes of one deep-grid call.
+
+    ``row_block == to`` is the resident call: the grid runs over batch
+    blocks and the coefficient and gain stacks are whole in VMEM.  Fewer
+    rows (a single-layer call) make the grid ``(row blocks, batch
+    blocks)``, rows outer: coefficients, gains, output rows and stage
+    planes are blocks indexed by the row, the input planes are whole
+    across row blocks, and the input cotangent is one partial sum a row
+    block, ``[R, Ti, P, B]``.  A row block's coefficient and gain inputs
+    are fetched once, into one buffer (``coef_in``/``gains_in``); its
+    gradient outputs (``coef``/``gains``) are double-buffered."""
+
+    def __init__(self, n, n_layers, to, ti, n_cols, batch_block,
+                 n_batch_blocks, row_block):
+        p = n // 2
+        bb, tr = batch_block, row_block
+        self.rows = to // tr
+        self.b_total = n_batch_blocks * bb
+        (self.coef_shape, self.gains_shape, self.xb, self.ob,
+         self.sb) = _deep_shapes(n, n_layers, tr, ti, n_cols, bb)
+        if self.rows == 1:
+            self.grid = (n_batch_blocks,)
+            self.coef = self.gains = _resident()
+            self.coef_in = self.gains_in = _resident()
+            self.x_plane = pl.BlockSpec((ti, p, bb), lambda b: (0, 0, b))
+            self.o_plane = pl.BlockSpec((to, p, bb), lambda b: (0, 0, b))
+            self.stage = pl.BlockSpec((n_layers, to, ti, p, bb),
+                                      lambda b: (0, 0, 0, 0, b))
+            self.dx = self.x_plane
+            self.dx_shape = (ti, p, self.b_total)
+        else:
+            self.grid = (self.rows, n_batch_blocks)
+
+            def coef_index(r, b):
+                return (0, r, 0, 0, 0, 0)
+
+            def gains_index(r, b):
+                return (0, r, 0, 0, 0)
+
+            once = pl.Buffered(1)
+            self.coef = pl.BlockSpec(self.coef_shape, coef_index)
+            self.gains = pl.BlockSpec(self.gains_shape, gains_index)
+            self.coef_in = pl.BlockSpec(self.coef_shape, coef_index,
+                                        pipeline_mode=once)
+            self.gains_in = pl.BlockSpec(self.gains_shape, gains_index,
+                                         pipeline_mode=once)
+            self.x_plane = pl.BlockSpec((ti, p, bb), lambda r, b: (0, 0, b))
+            self.o_plane = pl.BlockSpec((tr, p, bb), lambda r, b: (r, 0, b))
+            self.stage = pl.BlockSpec((n_layers, tr, ti, p, bb),
+                                      lambda r, b: (0, r, 0, 0, b))
+            self.dx = pl.BlockSpec((None, ti, p, bb),
+                                   lambda r, b: (r, 0, 0, b))
+            self.dx_shape = (self.rows, ti, p, self.b_total)
+
+    def compiler_params(self, coefs_in, coefs_out, planes):
+        """VMEM block shapes of the call's coefficient-class inputs and
+        outputs and of its batch-blocked planes."""
+        if self.rows == 1:
+            return _deep_compiler_params(coefs_in + coefs_out, planes)
+        return _deep_compiler_params(coefs_in, coefs_out + planes,
+                                     headroom=_ROW_BLOCK_HEADROOM)
+
+    def call(self, kernel, *, name, in_specs, out_specs, out_shape,
+             compiler_params, interpret, cost_estimate, scratch_shapes=()):
+        """The ``pallas_call``; a row-blocked one is named ``name`` on the
+        trace (the resident call keeps the enclosing jit's name)."""
+        extra = {} if self.rows == 1 else {"name": name}
+        return pl.pallas_call(kernel, grid=self.grid, in_specs=in_specs,
+                              scratch_shapes=scratch_shapes,
+                              out_specs=out_specs, out_shape=out_shape,
+                              compiler_params=compiler_params,
+                              interpret=interpret,
+                              cost_estimate=cost_estimate, **extra)
 
 
 def deepgrid_pallas_call(n: int, n_layers: int, to: int, ti: int,
                          n_cols: int, batch_block: int, n_batch_blocks: int,
-                         detect_last: bool, interpret: bool):
+                         detect_last: bool, interpret: bool,
+                         row_block: int):
     p = n // 2
-    b_total = n_batch_blocks * batch_block
-    coef, gains, xb, ob, _ = _deep_shapes(n, n_layers, to, ti, n_cols,
-                                          batch_block)
-    x_plane, o_plane, _ = _deep_plane_specs(n, n_layers, to, ti, batch_block)
+    g = _DeepGrid(n, n_layers, to, ti, n_cols, batch_block, n_batch_blocks,
+                  row_block)
     n_out = 2 if detect_last else 4
-    out_shape = [jax.ShapeDtypeStruct((to, p, b_total), jnp.float32)] * n_out
+    out_shape = [jax.ShapeDtypeStruct((to, p, g.b_total),
+                                      jnp.float32)] * n_out
     flops = _deep_flops_per_block(n, n_layers, to, ti, n_cols, batch_block)
-    return pl.pallas_call(
+    return g.call(
         functools.partial(deepgrid_kernel, detect_last=detect_last),
-        grid=(n_batch_blocks,),
-        in_specs=[_resident(), _smem(), _resident(), _smem(), _resident(),
-                  x_plane, x_plane, x_plane, x_plane],
-        out_specs=[o_plane] * n_out,
+        name="deepgrid_rows_kernel",
+        in_specs=[g.coef_in, _smem(), g.coef_in, _smem(), g.gains_in]
+        + [g.x_plane] * 4,
+        out_specs=[g.o_plane] * n_out,
         out_shape=out_shape,
-        compiler_params=_deep_compiler_params(
-            [coef, coef, gains], [xb] * 4 + [ob] * n_out),
+        compiler_params=g.compiler_params(
+            [g.coef_shape, g.coef_shape, g.gains_shape], [],
+            [g.xb] * 4 + [g.ob] * n_out),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=flops * n_batch_blocks,
-            bytes_accessed=((4 * ti + n_out * to) * batch_block * p * 4
+            bytes_accessed=((4 * ti * g.rows + n_out * to) * batch_block
+                            * p * 4
                             + _deep_coef_bytes(n_layers, to, ti, n_cols, p))
             * n_batch_blocks,
             transcendentals=n_layers * to * batch_block * p * 2
@@ -1065,33 +1273,31 @@ def deepgrid_pallas_call(n: int, n_layers: int, to: int, ti: int,
 def deepgrid_fwd_pallas_call(n: int, n_layers: int, to: int, ti: int,
                              n_cols: int, batch_block: int,
                              n_batch_blocks: int, detect_last: bool,
-                             interpret: bool):
+                             interpret: bool, row_block: int):
     p = n // 2
-    b_total = n_batch_blocks * batch_block
-    coef, gains, xb, ob, sb = _deep_shapes(n, n_layers, to, ti, n_cols,
-                                           batch_block)
-    x_plane, o_plane, stage = _deep_plane_specs(n, n_layers, to, ti,
-                                                batch_block)
+    g = _DeepGrid(n, n_layers, to, ti, n_cols, batch_block, n_batch_blocks,
+                  row_block)
     n_out = 2 if detect_last else 4
     out_shape = (
-        [jax.ShapeDtypeStruct((to, p, b_total), jnp.float32)] * n_out
-        + [jax.ShapeDtypeStruct((n_layers, to, ti, p, b_total),
+        [jax.ShapeDtypeStruct((to, p, g.b_total), jnp.float32)] * n_out
+        + [jax.ShapeDtypeStruct((n_layers, to, ti, p, g.b_total),
                                 jnp.float32)] * 8)
     flops = _deep_flops_per_block(n, n_layers, to, ti, n_cols, batch_block)
-    return pl.pallas_call(
+    return g.call(
         functools.partial(deepgrid_fwd_kernel, detect_last=detect_last),
-        grid=(n_batch_blocks,),
-        in_specs=[_resident(), _smem(), _resident(), _smem(), _resident(),
-                  x_plane, x_plane, x_plane, x_plane],
-        out_specs=[o_plane] * n_out + [stage] * 8,
+        name="deepgrid_rows_fwd_kernel",
+        in_specs=[g.coef_in, _smem(), g.coef_in, _smem(), g.gains_in]
+        + [g.x_plane] * 4,
+        out_specs=[g.o_plane] * n_out + [g.stage] * 8,
         out_shape=out_shape,
-        compiler_params=_deep_compiler_params(
-            [coef, coef, gains], [xb] * 4 + [ob] * n_out + [sb] * 8),
+        compiler_params=g.compiler_params(
+            [g.coef_shape, g.coef_shape, g.gains_shape], [],
+            [g.xb] * 4 + [g.ob] * n_out + [g.sb] * 8),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=flops * n_batch_blocks,
-            bytes_accessed=(((4 + 8 * n_layers * to) * ti + n_out * to)
-                            * batch_block * p * 4
+            bytes_accessed=(((4 * g.rows + 8 * n_layers * to) * ti
+                             + n_out * to) * batch_block * p * 4
                             + _deep_coef_bytes(n_layers, to, ti, n_cols, p))
             * n_batch_blocks,
             transcendentals=n_layers * to * batch_block * p * 2
@@ -1103,40 +1309,66 @@ def deepgrid_fwd_pallas_call(n: int, n_layers: int, to: int, ti: int,
 def deepgrid_bwd_pallas_call(n: int, n_layers: int, to: int, ti: int,
                              n_cols: int, batch_block: int,
                              n_batch_blocks: int, detect_last: bool,
-                             interpret: bool):
+                             interpret: bool, row_block: int):
+    """The backward, as ``f(coef_v, par_v, coef_u, par_u, gains, x
+    planes, stage planes, cotangents)``.  The resident call takes each
+    mesh's inverse and adjoint stacks, worked out here; a row-blocked
+    call derives them in the kernel (:func:`deepgrid_rows_bwd_kernel`),
+    and its input cotangent comes back as ``[R, Ti, P, B]`` partial
+    sums, one a row block."""
     p = n // 2
-    b_total = n_batch_blocks * batch_block
-    coef, gains, xb, ob, sb = _deep_shapes(n, n_layers, to, ti, n_cols,
-                                           batch_block)
-    x_plane, o_plane, stage = _deep_plane_specs(n, n_layers, to, ti,
-                                                batch_block)
+    g = _DeepGrid(n, n_layers, to, ti, n_cols, batch_block, n_batch_blocks,
+                  row_block)
     n_cot = 2 if detect_last else 4
     out_shape = (
-        [jax.ShapeDtypeStruct(coef, jnp.float32)] * 2
-        + [jax.ShapeDtypeStruct(gains, jnp.float32)]
-        + [jax.ShapeDtypeStruct((ti, p, b_total), jnp.float32)] * 4)
+        [jax.ShapeDtypeStruct(
+            (n_layers, to, ti, n_cols, p, 8), jnp.float32)] * 2
+        + [jax.ShapeDtypeStruct((n_layers, to, ti, p, 12), jnp.float32)]
+        + [jax.ShapeDtypeStruct(g.dx_shape, jnp.float32)] * 4)
     # inverse state recompute + adjoint cotangent + coefficient grads
     flops = 3 * _deep_flops_per_block(n, n_layers, to, ti, n_cols,
                                       batch_block)
-    return pl.pallas_call(
-        functools.partial(deepgrid_bwd_kernel, detect_last=detect_last),
-        grid=(n_batch_blocks,),
-        in_specs=[_resident(), _resident(), _smem(),
-                  _resident(), _resident(), _smem(), _resident()]
-        + [x_plane] * 4 + [stage] * 8 + [o_plane] * n_cot,
-        out_specs=[_resident()] * 3 + [x_plane] * 4,
+    if g.rows == 1:
+        kernel = deepgrid_bwd_kernel
+        coef_in = [g.coef_in, g.coef_in, _smem(), g.coef_in, g.coef_in,
+                   _smem(), g.gains_in]
+        scratch = []
+    else:
+        kernel = deepgrid_rows_bwd_kernel
+        coef_in = [g.coef_in, _smem(), g.coef_in, _smem(), g.gains_in]
+        scratch = [pltpu.VMEM(g.coef_shape, jnp.float32)] * 2
+    call = g.call(
+        functools.partial(kernel, detect_last=detect_last),
+        name="deepgrid_rows_bwd_kernel",
+        scratch_shapes=scratch,
+        in_specs=coef_in
+        + [g.x_plane] * 4 + [g.stage] * 8 + [g.o_plane] * n_cot,
+        out_specs=[g.coef] * 2 + [g.gains] + [g.dx] * 4,
         out_shape=out_shape,
-        compiler_params=_deep_compiler_params(
-            [coef] * 6 + [gains] * 2,
-            [xb] * 8 + [sb] * 8 + [ob] * n_cot),
+        # a row block's four coefficient-class inputs (or two and the
+        # two inverse scratch stacks) and the gains, once
+        compiler_params=g.compiler_params(
+            [g.coef_shape] * 4 + [g.gains_shape],
+            [g.coef_shape] * 2 + [g.gains_shape],
+            [g.xb] * 8 + [g.sb] * 8 + [g.ob] * n_cot),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=flops * n_batch_blocks,
-            bytes_accessed=(((8 + 8 * n_layers * to) * ti + n_cot * to)
-                            * batch_block * p * 4
+            bytes_accessed=(((8 * g.rows + 8 * n_layers * to) * ti
+                             + n_cot * to) * batch_block * p * 4
                             + 3 * _deep_coef_bytes(n_layers, to, ti, n_cols,
                                                    p)) * n_batch_blocks,
             transcendentals=3 * n_layers * to * batch_block * p * 2
             * n_batch_blocks,
         ),
     )
+    if g.rows > 1:
+        return call
+
+    def resident(coef_v, par_v, coef_u, par_u, *rest):
+        return call(inverse_coefficients(coef_v, axis=-1),
+                    adjoint_coefficients(coef_v, axis=-1), par_v,
+                    inverse_coefficients(coef_u, axis=-1),
+                    adjoint_coefficients(coef_u, axis=-1), par_u, *rest)
+
+    return resident

@@ -63,10 +63,11 @@ TRACE_COUNTS = {"mesh_apply": 0, "rfnn_linear": 0, "rfnn_network": 0,
                 "tiled_apply": 0, "tiled_apply_sharded": 0, "deep_apply": 0,
                 "deep_apply_sharded": 0}
 
-#: Instrumentation: ``(block rows, grid steps)`` of the batch grid of the
-#: last traced deep-grid call, keyed like :data:`TRACE_COUNTS` (the
-#: sharded entry records one device's shard).  Written at trace time only.
-DEEP_BLOCKS: dict[str, tuple[int, int]] = {}
+#: Instrumentation: ``(batch rows a block, batch blocks, tile rows a
+#: block, row blocks)`` of the grid of the last traced deep-grid call,
+#: keyed like :data:`TRACE_COUNTS` (the sharded entry records one
+#: device's single-layer call).  Written at trace time only.
+DEEP_BLOCKS: dict[str, tuple[int, int, int, int]] = {}
 
 #: Instrumentation: number of coefficient-pack builds actually executed by
 #: :func:`pack_deep_grid` (cache misses / tracer bypasses), keyed by the
@@ -409,21 +410,21 @@ def _layer_gains(n: int, la: dict) -> Array:
     return jnp.stack(rows).astype(jnp.float32)  # [12, P]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
-def _deepgrid_planes(deep, block_b, nb, interpret, detect_last,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+def _deepgrid_planes(deep, block_b, nb, rows, interpret, detect_last,
                      coef_v, coef_u, gains, xer, xei, xor, xoi):
     call = givens_mesh.deepgrid_pallas_call(
         deep.n, deep.n_layers, deep.to, deep.ti, deep.n_columns,
-        block_b, nb, detect_last, interpret)
+        block_b, nb, detect_last, interpret, rows)
     pv, pu = deep_grid_parity_arrays(deep)
     return tuple(call(coef_v, pv, coef_u, pu, gains, xer, xei, xor, xoi))
 
 
-def _deepgrid_planes_fwd(deep, block_b, nb, interpret, detect_last,
+def _deepgrid_planes_fwd(deep, block_b, nb, rows, interpret, detect_last,
                          coef_v, coef_u, gains, xer, xei, xor, xoi):
     call = givens_mesh.deepgrid_fwd_pallas_call(
         deep.n, deep.n_layers, deep.to, deep.ti, deep.n_columns,
-        block_b, nb, detect_last, interpret)
+        block_b, nb, detect_last, interpret, rows)
     pv, pu = deep_grid_parity_arrays(deep)
     n_out = 2 if detect_last else 4
     outs = call(coef_v, pv, coef_u, pu, gains, xer, xei, xor, xoi)
@@ -435,54 +436,58 @@ def _deepgrid_planes_fwd(deep, block_b, nb, interpret, detect_last,
                                  (xer, xei, xor, xoi), tuple(outs[n_out:]))
 
 
-def _deepgrid_planes_bwd(deep, block_b, nb, interpret, detect_last, res,
-                         cot):
+def _row_partials_sum(dx, to, rows):
+    """A row-blocked backward's ``[R, Ti, P, B]`` input-cotangent partials
+    (one per block of ``rows`` tile rows) summed over the blocks."""
+    if rows == to:
+        return tuple(dx)
+    return tuple(d.sum(axis=0) for d in dx)
+
+
+def _deepgrid_planes_bwd(deep, block_b, nb, rows, interpret, detect_last,
+                         res, cot):
     coef_v, coef_u, gains, xplanes, stages = res
     call = givens_mesh.deepgrid_bwd_pallas_call(
         deep.n, deep.n_layers, deep.to, deep.ti, deep.n_columns,
-        block_b, nb, detect_last, interpret)
+        block_b, nb, detect_last, interpret, rows)
     pv, pu = deep_grid_parity_arrays(deep)
-    dcv, dcu, dg, dxer, dxei, dxor, dxoi = call(
-        givens_mesh.inverse_coefficients(coef_v, axis=-1),
-        givens_mesh.adjoint_coefficients(coef_v, axis=-1), pv,
-        givens_mesh.inverse_coefficients(coef_u, axis=-1),
-        givens_mesh.adjoint_coefficients(coef_u, axis=-1), pu,
-        gains, *xplanes, *stages, *cot)
+    dcv, dcu, dg, *dx = call(coef_v, pv, coef_u, pu, gains, *xplanes,
+                             *stages, *cot)
     # the combine's transpose (sum of each input tile's cotangent over the
-    # To rows) already ran inside the kernel — dx comes back as [Ti, P, B]
-    return dcv, dcu, dg, dxer, dxei, dxor, dxoi
+    # To rows) ran inside the kernel, a row block at a time — dx comes
+    # back as [Ti, P, B]
+    return (dcv, dcu, dg) + _row_partials_sum(dx, deep.to, rows)
 
 
 _deepgrid_planes.defvjp(_deepgrid_planes_fwd, _deepgrid_planes_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _pack_deep_grid_impl(deep, hardware, layers):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _pack_deep_grid_impl(deep, hardware, positions, groups):
     """Stacked [L, To, Ti, C, P, 8] coefficients + [L, To, Ti, P, 12]
     gains for the deep megakernel, each tile's columns gathered onto the
     aligned kernel columns (identity cells where it sits a column out
     and in the padding).  Per-tile gains use the layer layout (g0 input
     screens, g1 attenuation + folded mid screens, g2 digital scale +
     output screen).  The row index is the last axis: the kernel reads
-    each row as a [P, 1] column along the pair-slot sublanes."""
-    src = deep_grid_column_sources(deep)               # [2, L, To, Ti, C]
-    # Tiles that share their schedules, column alignment and argument
-    # structure pack as ONE vmapped computation, so the traced program
-    # (and the chip compiler's time on it) grows with the number of
-    # distinct tile kinds rather than with L * To * Ti.
-    groups: dict = {}
-    for l, o, i in np.ndindex(deep.n_layers, deep.to, deep.ti):
-        ta = layers[l][o][i]
-        kind = (deep.layers[l][o][i], src[0, l, o, i].tobytes(),
-                src[1, l, o, i].tobytes(), jax.tree.structure(ta))
-        groups.setdefault(kind, []).append((l, o, i))
-    order, outs = [], []
-    for positions in groups.values():
-        l0, o0, i0 = positions[0]
-        sv, su = deep.layers[l0][o0][i0]
+    each row as a [P, 1] column along the pair-slot sublanes.
 
-        def one(ta, sv=sv, su=su, sv_src=src[0, l0, o0, i0],
-                su_src=src[1, l0, o0, i0]):
+    ``groups``: per-tile dicts (see :func:`pack_deep_grid`) stacked along
+    their leading axes, one group per argument structure: ``positions``
+    ``None`` for one group of ``[L, To, Ti, ...]`` leaves, else
+    ``positions[g]`` the flat ``(l, o, i)`` index of each of group g's
+    tiles along its one leading axis."""
+    src = deep_grid_column_sources(deep)               # [2, L, To, Ti, C]
+    lead = (deep.n_layers, deep.to, deep.ti)
+    src = src.reshape((2, -1, src.shape[-1]))
+    scheds = [pair for grid in deep.layers for row in grid for pair in row]
+
+    def packer(f):
+        """One tile's packing, for the tiles of tile ``f``'s kind."""
+        sv, su = scheds[f]
+        sv_src, su_src = src[0, f], src[1, f]
+
+        def one(ta):
             return (jnp.swapaxes(align_columns(_mesh_coefficients(
                         sv, ta["v"], hardware, ta.get("key_v")), sv_src),
                         -1, -2),
@@ -490,13 +495,45 @@ def _pack_deep_grid_impl(deep, hardware, layers):
                         su, ta["u"], hardware, ta.get("key_u")), su_src),
                         -1, -2),
                     _layer_gains(deep.n, ta).T)
+        return one
 
-        stacked = jax.tree.map(lambda *a: jnp.stack(a),
-                               *(layers[l][o][i] for l, o, i in positions))
-        outs.append(jax.vmap(one)(stacked))
-        order += [np.ravel_multi_index(p, (deep.n_layers, deep.to, deep.ti))
-                  for p in positions]
-    lead = (deep.n_layers, deep.to, deep.ti)
+    def kind(f):
+        return scheds[f], src[0, f].tobytes(), src[1, f].tobytes()
+
+    if positions is None:
+        if len({kind(f) for f in range(len(scheds))}) == 1:
+            # one kind: vmapped over the tile axes as they are, so a
+            # computation split on tile rows stays split
+            layer = jax.vmap(jax.vmap(packer(0)))
+            if givens_mesh.deep_row_block(deep.n, 1, deep.to, deep.ti,
+                                          deep.n_columns) == deep.to:
+                return jax.vmap(layer)(groups[0])
+            # a grid whose layers run in row blocks packs a layer at a
+            # time under remat: the per-cell 2x2 matrices ([.., C, P, 2,
+            # 2], which the TPU pads 64-fold) of one layer are all that
+            # the step's forward or backward then holds of the packing
+            return jax.lax.map(jax.checkpoint(layer), groups[0])
+        positions = (range(len(scheds)),)
+        groups = tuple(jax.tree.map(
+            lambda a: a.reshape((-1,) + a.shape[len(lead):]), g)
+            for g in groups)
+    # Tiles that share their schedules and column alignment pack as ONE
+    # vmapped computation, so the traced program (and the chip compiler's
+    # time on it) grows with the number of distinct tile kinds rather
+    # than with L * To * Ti.
+    order, outs = [], []
+    for pos, group in zip(positions, groups):
+        kinds: dict = {}
+        for j, f in enumerate(pos):
+            kinds.setdefault(kind(f), []).append(j)
+        for members in kinds.values():
+            part = group
+            if len(members) < len(pos):
+                idx = jnp.asarray(members)
+                part = jax.tree.map(lambda a: jnp.take(a, idx, axis=0),
+                                    group)
+            outs.append(jax.vmap(packer(pos[members[0]]))(part))
+            order += [pos[j] for j in members]
     inverse = np.argsort(order)
 
     def place(parts):
@@ -506,6 +543,46 @@ def _pack_deep_grid_impl(deep, hardware, layers):
         return flat.reshape(lead + flat.shape[1:])
 
     return tuple(place(parts) for parts in zip(*outs))
+
+
+#: The stacked entry's names: ``<mesh>_<param>`` of the V and U meshes
+#: and the tile's own arrays, each ``[L, To, Ti, ...]``.
+_STACKED_TILE_KEYS = {
+    **{f"{m}_{k}": (m, k) for m in ("v", "u")
+       for k in ("theta", "phi", "alpha_in", "alpha")},
+    **{k: (k,) for k in ("atten", "scale", "key_v", "key_u")}}
+
+
+def _stacked_groups(tiles: dict):
+    """The stacked entry's ``[L, To, Ti, ...]`` arrays as one group of
+    per-tile dicts."""
+    unknown = set(tiles) - set(_STACKED_TILE_KEYS)
+    if unknown:
+        raise ValueError(f"unknown stacked tile parameters {sorted(unknown)};"
+                         f" known: {sorted(_STACKED_TILE_KEYS)}")
+    group: dict = {"v": {}, "u": {}}
+    for name, a in tiles.items():
+        *outer, key = _STACKED_TILE_KEYS[name]
+        (group[outer[0]] if outer else group)[key] = a
+    return None, (group,)
+
+
+def _nested_groups(layers):
+    """Nested ``[L][To][Ti]`` tile dicts stacked, one group per argument
+    structure (in tile order): ``[L, To, Ti, ...]`` leaves when every
+    tile shares one, else one leading tile axis a group."""
+    lead = (len(layers), len(layers[0]), len(layers[0][0]))
+    flat = [ta for layer in layers for row in layer for ta in row]
+    by_kind: dict = {}
+    for f, ta in enumerate(flat):
+        by_kind.setdefault(jax.tree.structure(ta), []).append(f)
+    positions = tuple(tuple(pos) for pos in by_kind.values())
+    groups = tuple(jax.tree.map(lambda *a: jnp.stack(a),
+                                *(flat[f] for f in pos)) for pos in positions)
+    if len(positions) > 1:
+        return positions, groups
+    return None, tuple(jax.tree.map(
+        lambda a: a.reshape(lead + a.shape[1:]), g) for g in groups)
 
 
 def _contains_tracer(tree) -> bool:
@@ -567,12 +644,22 @@ def pack_deep_grid(layers, *, n: int, plans=None,
                    _event: str = "deep_apply"):
     """Emit the deep megakernel inputs for L layers of (To x Ti) tiles.
 
-    ``layers``: nested ``[L][To][Ti]`` sequence of per-tile dicts with
-    keys ``v``/``u`` (mesh params, optional ``alpha_in``/``alpha``
-    screens), ``atten`` ([n] diagonal), optional ``scale`` (digital
-    gamma) and, with ``hardware``, optional ``key_v``/``key_u``
-    phase-noise keys.  ``plans``: matching ``[L][To][Ti]`` nesting of
-    ``(v_plan, u_plan)`` pairs (or ``None`` entries for Clements).
+    ``layers``, in either of two forms:
+
+    * stacked: a dict of ``[L, To, Ti, ...]`` arrays — ``v_theta``,
+      ``v_phi``, ``u_theta``, ``u_phi`` (mesh phases), ``atten`` ([..., n]
+      diagonal), optional ``v_alpha_in``/``u_alpha_in`` and
+      ``v_alpha``/``u_alpha`` (screens), ``scale`` (digital gamma) and,
+      with ``hardware``, ``key_v``/``key_u`` (phase-noise keys).  A
+      training step takes its parameters this way: a handful of
+      buffers, however many tiles;
+    * nested: a ``[L][To][Ti]`` sequence of per-tile dicts with keys
+      ``v``/``u`` (mesh params, optional ``alpha_in``/``alpha``),
+      ``atten``, optional ``scale``, ``key_v``/``key_u``.  It is stacked
+      onto the same packing, a group per argument structure.
+
+    ``plans``: matching ``[L][To][Ti]`` nesting of ``(v_plan, u_plan)``
+    pairs (or ``None`` entries for Clements).
 
     Returns ``(deep, (coef_v, coef_u, gains))``: the static
     :class:`~repro.kernels.schedule.DeepGridSchedule` plus the stacked
@@ -584,13 +671,19 @@ def pack_deep_grid(layers, *, n: int, plans=None,
     arrays do zero packing work; tracer leaves bypass so gradients flow
     through packing.
     """
-    layers = tuple(tuple(tuple(row) for row in layer) for layer in layers)
-    deep = deep_grid_schedule(n, len(layers), len(layers[0]),
-                              len(layers[0][0]), plans)
+    if isinstance(layers, dict):
+        lead = layers["atten"].shape[:3]
+    else:
+        layers = tuple(tuple(tuple(row) for row in layer) for layer in layers)
+        lead = (len(layers), len(layers[0]), len(layers[0][0]))
+    deep = deep_grid_schedule(n, *lead, plans)
 
     def build():
         PACK_EVENTS[_event] += 1
-        return _pack_deep_grid_impl(deep, hardware, layers)
+        positions, groups = (_stacked_groups(layers)
+                                if isinstance(layers, dict)
+                                else _nested_groups(layers))
+        return _pack_deep_grid_impl(deep, hardware, positions, groups)
 
     if _contains_tracer(layers):
         return deep, build()
@@ -626,13 +719,31 @@ def _vmem_auto_block(b: int, block_b: int | None, n: int,
     return _auto_block(b, block_b, quantum=128)
 
 
-def _deep_planes_per_row(deep) -> int:
+def _deep_planes_per_row(deep, *, n_layers: int | None = None,
+                         rows: int | None = None) -> int:
     """Resident [P, block] planes per batch row for the deep kernel: 8
     stage-residual planes per tile per layer, 4 input and 4 output planes
     per tile column / row slot, ~4 working planes.  Reduces to the
-    network kernel's ``8 L + 12`` at To = Ti = 1."""
-    return 8 * deep.n_layers * deep.to * deep.ti + 4 * deep.ti \
-        + 4 * deep.to + 4
+    network kernel's ``8 L + 12`` at To = Ti = 1.  ``n_layers``/``rows``:
+    the call's layers and tile rows a grid step, when the call runs fewer
+    than the whole grid (one layer of a sharded chain, a row block); the
+    input planes are whole either way."""
+    n_layers = deep.n_layers if n_layers is None else n_layers
+    rows = deep.to if rows is None else rows
+    return 8 * n_layers * rows * deep.ti + 4 * deep.ti + 4 * rows + 4
+
+
+def _deep_blocks(deep, n_layers: int, to: int, b: int, block_b,
+                 interpret: bool) -> tuple[int, int]:
+    """``(batch rows, tile rows)`` a grid step of a deep-grid call over
+    ``n_layers`` layers of ``to`` tile rows and ``b`` batch rows: the
+    row block from the call's coefficient VMEM
+    (:func:`givens_mesh.deep_row_block`), then the batch block from the
+    planes of one row block."""
+    rows = givens_mesh.deep_row_block(deep.n, n_layers, to, deep.ti,
+                                      deep.n_columns)
+    planes = _deep_planes_per_row(deep, n_layers=n_layers, rows=rows)
+    return _vmem_auto_block(b, block_b, deep.n, planes, interpret), rows
 
 
 def _split_tile_planes(xt):
@@ -668,12 +779,12 @@ def _deep_apply_impl(deep, block_b, interpret, detect_last, trace_key,
     n, to, ti = deep.n, deep.to, deep.ti
     batch_shape = x.shape[:-1]
     xt = x.reshape((-1, ti, n)).astype(jnp.complex64)
-    bb = _vmem_auto_block(xt.shape[0], block_b, n,
-                          _deep_planes_per_row(deep), interpret)
+    bb, rows = _deep_blocks(deep, deep.n_layers, to, xt.shape[0], block_b,
+                            interpret)
     xt, b_orig = _pad_batch(xt, bb)
     nb = xt.shape[0] // bb
-    DEEP_BLOCKS[trace_key] = (bb, nb)
-    outs = _deepgrid_planes(deep, bb, nb, interpret, detect_last,
+    DEEP_BLOCKS[trace_key] = (bb, nb, rows, to // rows)
+    outs = _deepgrid_planes(deep, bb, nb, rows, interpret, detect_last,
                             coef_v, coef_u, gains, *_split_tile_planes(xt))
     return _merge_deep_out(outs, detect_last, to, n, b_orig, batch_shape)
 
@@ -693,22 +804,25 @@ def _deep_apply_impl(deep, block_b, interpret, detect_last, trace_key,
 # fuse across devices: a layer's re-detected outputs are each next
 # layer's *full* input, so L > 1 runs as a python chain of single-layer
 # sharded calls (one resharding row->replicated per boundary, inserted by
-# GSPMD), with the boundary |detect| taken inside the kernel
+# the partitioner), with the boundary |detect| taken inside the kernel
 # (``detect_last=True``) so its zero-guarded backward keeps padded batch
-# rows grad-exact.
+# rows grad-exact.  Each device's call is sized on its own: its row
+# slab, one layer, and row blocks of it where the slab's coefficient
+# stacks outgrow VMEM.
 #
 # Coefficient operands enter the shard_map REPLICATED (in_spec P()) and
 # each device slices its own row slab (axis 1 of [L, To, Ti, ...])
 # in-body by ``axis_index``; the backward all-gathers the coefficient
-# grads back to replicated.  They are small (L*To*Ti*C*8*P floats), and
-# splitting them on the row axis instead trips a GSPMD bug on this jax
-# version: under an enclosing jit on a multi-axis mesh, concatenate/
-# stack-built values (exactly what ``pack_deep_grid`` emits when traced,
-# e.g. under ``jit(grad(...))``) feeding a shard_map along a partitioned
-# axis get mis-partitioned — row shards arrive summed, corrupting forward
-# and backward alike.  Replicated operands take the all-gather path,
-# which is sound (the batch planes are safe either way: they are built
-# with ``jnp.pad`` + strided slices — see ``_pad_batch``).
+# grads back to replicated.  Splitting them on the row axis instead
+# tripped a GSPMD bug on jax 0.4.37: under an enclosing jit on a
+# multi-axis mesh, concatenate/stack-built values (exactly what
+# ``pack_deep_grid`` emits when traced, e.g. under ``jit(grad(...))``)
+# feeding a shard_map along a partitioned axis got mis-partitioned — row
+# shards arrived summed, corrupting forward and backward alike.  On jax
+# 0.9.0 the split operands pass the CPU parity tests, but no chip has
+# run them yet.  Replicated operands take the all-gather path, which is
+# sound (the batch planes are safe either way: they are built with
+# ``jnp.pad`` + strided slices — see ``_pad_batch``).
 
 
 def _shard_specs(row_axis: str, data_axis: str):
@@ -734,9 +848,9 @@ def _row_slab(row_axis, to_local, axis):
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8))
+                   nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8, 9))
 def _deepgrid_planes_sharded(deep, layer, mesh, row_axis, data_axis,
-                             block_b, nb, interpret, detect_last,
+                             block_b, nb, rows, interpret, detect_last,
                              coef_v, coef_u, gains, xer, xei, xor, xoi):
     specs = _shard_specs(row_axis, data_axis)
     to_local = deep.to // mesh.shape[row_axis]
@@ -747,7 +861,7 @@ def _deepgrid_planes_sharded(deep, layer, mesh, row_axis, data_axis,
         sl = _row_slab(row_axis, to_local, 1)
         call = givens_mesh.deepgrid_pallas_call(
             deep.n, 1, to_local, deep.ti, deep.n_columns, block_b, nb,
-            detect_last, interpret)
+            detect_last, interpret, rows)
         return tuple(call(sl(cv), pv, sl(cu), pu, sl(g),
                           xer, xei, xor, xoi))
 
@@ -759,7 +873,7 @@ def _deepgrid_planes_sharded(deep, layer, mesh, row_axis, data_axis,
 
 
 def _deepgrid_planes_sharded_fwd(deep, layer, mesh, row_axis, data_axis,
-                                 block_b, nb, interpret, detect_last,
+                                 block_b, nb, rows, interpret, detect_last,
                                  coef_v, coef_u, gains, xer, xei, xor, xoi):
     specs = _shard_specs(row_axis, data_axis)
     to_local = deep.to // mesh.shape[row_axis]
@@ -770,7 +884,7 @@ def _deepgrid_planes_sharded_fwd(deep, layer, mesh, row_axis, data_axis,
         sl = _row_slab(row_axis, to_local, 1)
         call = givens_mesh.deepgrid_fwd_pallas_call(
             deep.n, 1, to_local, deep.ti, deep.n_columns, block_b, nb,
-            detect_last, interpret)
+            detect_last, interpret, rows)
         return tuple(call(sl(cv), pv, sl(cu), pu, sl(g),
                           xer, xei, xor, xoi))
 
@@ -787,8 +901,8 @@ def _deepgrid_planes_sharded_fwd(deep, layer, mesh, row_axis, data_axis,
 
 
 def _deepgrid_planes_sharded_bwd(deep, layer, mesh, row_axis, data_axis,
-                                 block_b, nb, interpret, detect_last, res,
-                                 cot):
+                                 block_b, nb, rows, interpret, detect_last,
+                                 res, cot):
     coef_v, coef_u, gains, xplanes, stages = res
     specs = _shard_specs(row_axis, data_axis)
     to_local = deep.to // mesh.shape[row_axis]
@@ -800,18 +914,14 @@ def _deepgrid_planes_sharded_bwd(deep, layer, mesh, row_axis, data_axis,
         cv, cu, g = sl(cv), sl(cu), sl(g)
         call = givens_mesh.deepgrid_bwd_pallas_call(
             deep.n, 1, to_local, deep.ti, deep.n_columns, block_b, nb,
-            detect_last, interpret)
-        dcv, dcu, dg, dxer, dxei, dxor, dxoi = call(
-            givens_mesh.inverse_coefficients(cv, axis=-1),
-            givens_mesh.adjoint_coefficients(cv, axis=-1), pv,
-            givens_mesh.inverse_coefficients(cu, axis=-1),
-            givens_mesh.adjoint_coefficients(cu, axis=-1), pu,
-            g, xer, xei, xor, xoi, *rest)
-        # the kernel already summed its local rows' input-cotangent
-        # partials; the psum over the row axis completes the transpose of
-        # the (now distributed) row combine
+            detect_last, interpret, rows)
+        dcv, dcu, dg, *dx = call(cv, pv, cu, pu, g, xer, xei, xor, xoi,
+                                 *rest)
+        # the kernel summed its local rows' input-cotangent partials (a
+        # row block at a time); the psum over the row axis completes the
+        # transpose of the (now distributed) row combine
         dx = tuple(jax.lax.psum(d, row_axis)
-                   for d in (dxer, dxei, dxor, dxoi))
+                   for d in _row_partials_sum(dx, to_local, rows))
         # coefficient grads: psum over the batch axis (the usual DP
         # gradient reduction of per-shard partials), then an all-gather
         # over the row axis (axis 1 = To of the [L, To, Ti, ...] stacks)
@@ -847,18 +957,21 @@ def _deep_apply_sharded_impl(deep, mesh, row_axis, data_axis, block_b,
     batch_shape = x.shape[:-1]
     xt = x.reshape((-1, ti, n)).astype(jnp.complex64)
     n_data = mesh.shape[data_axis]
-    bb = _vmem_auto_block(max(1, -(-xt.shape[0] // n_data)), block_b, n,
-                          _deep_planes_per_row(deep), interpret)
+    to_local = to // mesh.shape[row_axis]
+    # every device runs one layer of its own row slab on its batch shard
+    bb, rows = _deep_blocks(deep, 1, to_local,
+                            max(1, -(-xt.shape[0] // n_data)), block_b,
+                            interpret)
     # every device's batch shard must tile into whole blocks
     xt, b_orig = _pad_batch(xt, bb * n_data)
     nb = xt.shape[0] // n_data // bb
-    DEEP_BLOCKS[trace_key] = (bb, nb)
+    DEEP_BLOCKS[trace_key] = (bb, nb, rows, to_local // rows)
     planes = _split_tile_planes(xt)
     outs = None
     for l in range(deep.n_layers):
         last = l == deep.n_layers - 1
         outs = _deepgrid_planes_sharded(
-            deep, l, mesh, row_axis, data_axis, bb, nb, interpret,
+            deep, l, mesh, row_axis, data_axis, bb, nb, rows, interpret,
             detect_last if last else True,
             coef_v[l:l + 1], coef_u[l:l + 1], gains[l:l + 1], *planes)
         if not last:
@@ -908,6 +1021,9 @@ def deep_apply(layers, x: Array, *, n: int, plans=None,
     target (large blocks for small grids, shrinking with n, L, To, Ti).
     The planes carry the batch on lanes, so an explicit ``block_b`` is a
     ceiling in whole 128-row lane tiles: a value below 128 counts as 128.
+    A single-layer call whose coefficient stacks outgrow VMEM runs in
+    blocks of tile rows (``givens_mesh.deep_row_block``); a multi-layer
+    call is never row-blocked.
 
     ``mesh``: an optional 2-axis ``jax.sharding.Mesh`` — each layer's
     grid then shards over ``(row_axis, data_axis)`` via shard_map: tile

@@ -193,8 +193,10 @@ def test_deepgrid_block_rows_and_grid_steps(batch, block):
     """The chip's block arithmetic for the 64-wide L=4 grid of 16x16 tiles
     (548 planes a row, P = 8 sublanes x 4 B each under the 4 MiB target):
     a 1024-row training batch runs in 8 grid steps of 128 rows, a 64-row
-    serving panel whole in one.  Traced for the chip (``interpret=False``)
-    without compiling, so the recorded block is the one a v5e runs."""
+    serving panel whole in one.  The stacks fit VMEM, so the grid is one
+    row block of all 4 tile rows: the resident call.  Traced for the chip
+    (``interpret=False``) without compiling, so the recorded block is the
+    one a v5e runs."""
     n, depth, g = 16, 4, 4
     deep = deep_grid_schedule(n, depth, g, g, None)
     rows = ops._vmem_auto_block(batch, None, n, ops._deep_planes_per_row(deep),
@@ -207,7 +209,7 @@ def test_deepgrid_block_rows_and_grid_steps(batch, block):
     ops._deep_apply_impl.clear_cache()  # a cached trace records nothing
     jax.eval_shape(lambda ls, xx: ops.deep_apply(ls, xx, n=n,
                                                  interpret=False), layers, x)
-    assert ops.DEEP_BLOCKS["deep_apply"] == block
+    assert ops.DEEP_BLOCKS["deep_apply"] == block + (g, 1)
 
 
 # ---------------------------------------------------------------------------

@@ -127,3 +127,39 @@ def test_rfnn_linear_kernels_compile_for_v5e(one_chip, compiled_kernels):
     assert _n_kernels(_compile(apply, *args, x)) >= 1
     assert _n_kernels(_compile(jax.grad(loss, argnums=(0, 1, 2)),
                                *args, x)) >= 2
+
+
+@pytest.mark.parametrize("kind", ["fwd", "bwd"])
+def test_row_blocked_deep_grid_kernels_compile_for_v5e(one_chip, kind):
+    """One chip's single-layer call of the 1024-wide grid of 16x16 Reck
+    tiles on a 2 x 2 mesh: 32 tile rows of 64 tiles, 29 columns, 2048
+    batch rows.  Its stacks outgrow VMEM, so it runs one tile row a block
+    (32 row blocks x 16 batch blocks of 128 rows); the backward derives
+    the inverse coefficients into VMEM scratch.  Both fit the scoped
+    VMEM they ask for."""
+    from repro.kernels import givens_mesh
+
+    n, to, ti, cols, bb, nb = 16, 32, 64, 29, 128, 16
+    rows = givens_mesh.deep_row_block(n, 1, to, ti, cols)
+    assert rows == 1
+    p = n // 2
+    coef = jax.ShapeDtypeStruct((1, to, ti, cols, p, 8), jnp.float32,
+                                sharding=one_chip)
+    par = jax.ShapeDtypeStruct((1, cols), jnp.int32, sharding=one_chip)
+    gains = jax.ShapeDtypeStruct((1, to, ti, p, 12), jnp.float32,
+                                 sharding=one_chip)
+    plane = jax.ShapeDtypeStruct((ti, p, bb * nb), jnp.float32,
+                                 sharding=one_chip)
+    stage = jax.ShapeDtypeStruct((1, to, ti, p, bb * nb), jnp.float32,
+                                 sharding=one_chip)
+    out = jax.ShapeDtypeStruct((to, p, bb * nb), jnp.float32,
+                               sharding=one_chip)
+    build = (givens_mesh.deepgrid_fwd_pallas_call if kind == "fwd"
+             else givens_mesh.deepgrid_bwd_pallas_call)
+    call = build(n, 1, to, ti, cols, bb, nb, True, False, rows)
+    args = [coef, par, coef, par, gains] + [plane] * 4
+    if kind == "bwd":
+        args += [stage] * 8 + [out] * 2
+    compiled = _compile(call, *args)
+    assert _n_kernels(compiled) == 1
+    assert f"deepgrid_rows_{kind}_kernel" in compiled.as_text()
